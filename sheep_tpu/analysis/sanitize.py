@@ -19,7 +19,7 @@ Three checks, all free when the env var is unset:
   memory IS host memory, there is no transfer — which is exactly why
   the dunder traps exist: they make the sanitizer testable in CI.
   ``np.asarray`` is deliberately NOT trapped: it is the explicit pull
-  form (JAX's own transfer-guard taxonomy calls it an explicit
+  form (JAX's own transfer guard classifies it as an explicit
   transfer), and the static sync rule already requires it to sit on a
   pragma-annotated line.
 - **donation poisoning**: :func:`check_donated` asserts buffers passed

@@ -18,6 +18,7 @@ from sheep_tpu.backends.base import Partitioner, register
 from sheep_tpu.parallel.bigv import BigVPipeline, cached_pipeline
 from sheep_tpu.parallel.mesh import shards_mesh
 from sheep_tpu.types import PartitionResult, check_tpu_vertex_range
+from sheep_tpu.utils.platform import device_identity
 
 
 @register
@@ -92,7 +93,8 @@ class TpuBigVBackend(Partitioner):
                          # clamp formula (which could silently drift)
                          "chunk_edges_effective": float(cs),
                          **{k_: float(v) for k_, v in
-                            out.get("build_stats", {}).items()}},
+                            out.get("build_stats", {}).items()},
+                         **device_identity()},
             tree={"parent": out["parent"], "pos": out["pos"],
                   "deg": out["degrees"]} if opts.get("keep_tree") else None,
         )

@@ -16,6 +16,7 @@ from sheep_tpu.backends.base import Partitioner, register
 from sheep_tpu.parallel.mesh import shards_mesh
 from sheep_tpu.parallel.pipeline import ShardedPipeline, cached_pipeline
 from sheep_tpu.types import PartitionResult, check_tpu_vertex_range
+from sheep_tpu.utils.platform import device_identity
 
 
 @register
@@ -31,7 +32,7 @@ class TpuShardedBackend(Partitioner):
     def __init__(self, chunk_edges: int = 1 << 22, lift_levels: int = 0,
                  alpha: float = 1.0, n_devices: int | None = None,
                  segment_rounds: int = 32, warm_schedule=((1, 8),),
-                 dispatch_batch: int = 0, inflight: int = 0,
+                 dispatch_batch: int = 1, inflight: int = 1,
                  donate_buffers: bool | None = None):
         self.chunk_edges = chunk_edges
         self.lift_levels = lift_levels
@@ -39,17 +40,20 @@ class TpuShardedBackend(Partitioner):
         self.n_devices = n_devices
         self.segment_rounds = segment_rounds
         self.warm_schedule = tuple(warm_schedule)
-        # batched segment dispatch (see ShardedPipeline): 0 = auto
-        # (per-segment on cpu-jax; HBM-model-sized N on accelerators),
-        # 1 = per-segment, N > 1 = stage N sharded batches per program
-        if dispatch_batch < 0:
-            raise ValueError("dispatch_batch must be >= 0 (0 = auto)")
+        # batched segment dispatch (see ShardedPipeline): 1 (with
+        # inflight 1) = the adaptive per-segment fold, N > 1 = stage N
+        # sharded batches per program. The default is per-segment on
+        # every platform, as for the tpu backend, whose batched twin
+        # lost 25x on a v5e (chip run, PR 21); on the CPU at RMAT-16
+        # the batched sharded fold runs about as many rounds (176 vs
+        # 184), each N chunks wide
+        if dispatch_batch < 1:
+            raise ValueError("dispatch_batch must be >= 1")
         self.dispatch_batch = dispatch_batch
         # asynchronous dispatch pipeline depth for the batched path
-        # (see ShardedPipeline.build_step_batch): 0 = auto (2 on
-        # accelerators, 1 = synchronous on cpu-jax)
-        if inflight < 0:
-            raise ValueError("inflight must be >= 0 (0 = auto)")
+        # (see ShardedPipeline.build_step_batch): 1 = synchronous
+        if inflight < 1:
+            raise ValueError("inflight must be >= 1")
         self.inflight = inflight
         # donate per-device tables + staging blocks into the batched
         # executions (None = auto: on for the batched/pipelined path)
@@ -80,13 +84,11 @@ class TpuShardedBackend(Partitioner):
         # chunk sizing (and checkpoint fingerprints) cannot diverge
         cs = stream.clamp_chunk_edges(self.chunk_edges,
                                       parts=mesh.devices.size)
-        from sheep_tpu.backends.tpu_backend import resolve_dispatch_batch, \
-            resolve_inflight
+        from sheep_tpu.backends.tpu_backend import check_dispatch_batch
 
-        inflight = resolve_inflight(self.inflight)
+        inflight = self.inflight
         donate = True if self.donate_buffers is None else self.donate_buffers
-        nb = resolve_dispatch_batch(self.dispatch_batch, n, cs,
-                                    inflight=inflight, donate=donate)
+        nb = check_dispatch_batch(self.dispatch_batch)
         pipe = cached_pipeline(n, cs, mesh, lift_levels=self.lift_levels,
                                segment_rounds=self.segment_rounds,
                                warm_schedule=self.warm_schedule,
@@ -114,7 +116,8 @@ class TpuShardedBackend(Partitioner):
                               else v if isinstance(v, (int, float))
                               else str(v))
                          for k_, v in {**out.get("build_stats", {}),
-                                       **out.get("merge_stats", {})}.items()},
+                                       **out.get("merge_stats", {}),
+                                       **device_identity()}.items()},
             tree={"parent": np.asarray(out["parent"]), "pos": out["pos"],
                   "deg": out["degrees"]} if opts.get("keep_tree") else None,
         )

@@ -4,12 +4,22 @@ pybind11 is not available in this environment, so the C++ core exposes a
 plain C ABI over caller-allocated numpy buffers. The library is built
 lazily with make on first use; failure to build leaves the ``cpu`` backend
 unregistered (callers fall back to ``pure``).
+
+The build is keyed, not timed: ``libsheep_core.so.key`` next to the
+library holds a hash of the committed sources (``sheep_core.cpp``,
+``Makefile``) and of the host CPU (the Makefile compiles with
+``-march=native``). A library whose key differs — other sources, or
+built on another CPU and copied here — is rebuilt before it is loaded,
+never loaded as it is.
 """
 
 from __future__ import annotations
 
 import ctypes
+import fcntl
+import hashlib
 import os
+import platform
 import subprocess
 from typing import Optional
 
@@ -36,16 +46,62 @@ class _f64p_or_null(_f64p):
         return _f64p.from_param(obj)
 
 
+def _host_cpu() -> str:
+    """What ``-march=native`` compiles for: machine, CPU model, flags."""
+    ident = [platform.machine()]
+    try:
+        with open("/proc/cpuinfo") as f:
+            for line in f:
+                key = line.split(":", 1)[0].strip()
+                if key in ("model name", "flags", "Features",
+                           "CPU part") and line not in ident:
+                    ident.append(line)
+                if line.strip() == "":
+                    break  # the first processor block says it all
+    except OSError:
+        ident.append(platform.processor())
+    return "".join(ident)
+
+
+def build_key() -> str:
+    """Hash of the committed sources plus the host CPU."""
+    h = hashlib.sha256()
+    for name in ("sheep_core.cpp", "Makefile"):
+        with open(os.path.join(_CSRC, name), "rb") as f:
+            h.update(f.read())
+    h.update(_host_cpu().encode())
+    return h.hexdigest()
+
+
 def _build() -> None:
-    src = os.path.join(_CSRC, "sheep_core.cpp")
-    if os.path.exists(_SO) and os.path.getmtime(_SO) >= os.path.getmtime(src):
+    key_path = _SO + ".key"
+    key = build_key()
+
+    def fresh() -> bool:
+        try:
+            with open(key_path) as f:
+                return f.read().strip() == key and os.path.exists(_SO)
+        except OSError:
+            return False
+
+    if fresh():
         return
-    subprocess.run(
-        ["make", "-C", _CSRC],
-        check=True,
-        capture_output=True,
-        text=True,
-    )
+    # one builder at a time (test workers, sheepd + clients): the loser
+    # of the lock finds the winner's library fresh and loads it
+    with open(os.path.join(_CSRC, ".build.lock"), "w") as lock:
+        fcntl.flock(lock, fcntl.LOCK_EX)
+        if fresh():
+            return
+        tmp = f"libsheep_core.so.{os.getpid()}.tmp"
+        try:
+            subprocess.run(["make", "-B", "-C", _CSRC, f"TARGET={tmp}"],
+                           check=True, capture_output=True, text=True)
+            os.replace(os.path.join(_CSRC, tmp), _SO)
+        finally:
+            if os.path.exists(os.path.join(_CSRC, tmp)):
+                os.unlink(os.path.join(_CSRC, tmp))
+        with open(key_path, "w") as f:
+            f.write(key + "\n")
 
 
 def load() -> ctypes.CDLL:

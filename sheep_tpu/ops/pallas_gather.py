@@ -47,13 +47,7 @@ def _build(table_len: int, n_idx: int, block: int, interpret: bool):
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
-
-    try:  # memory-space constraint is TPU-only; interpret mode runs anywhere
-        from jax.experimental.pallas import tpu as pltpu
-
-        vmem = pltpu.VMEM
-    except Exception:  # pragma: no cover - non-TPU jaxlib
-        vmem = None
+    from jax.experimental.pallas import tpu as pltpu
 
     def kernel(table_ref, idx_ref, out_ref):
         # whole table resident in VMEM; Mosaic decides whether an
@@ -62,9 +56,12 @@ def _build(table_len: int, n_idx: int, block: int, interpret: bool):
                                 mode="clip")
 
     def spec(block_shape, index_map):
-        if vmem is None or interpret:
+        # the memory-space constraint is TPU-only; interpret mode
+        # runs anywhere
+        if interpret:
             return pl.BlockSpec(block_shape, index_map)
-        return pl.BlockSpec(block_shape, index_map, memory_space=vmem)
+        return pl.BlockSpec(block_shape, index_map,
+                            memory_space=pltpu.VMEM)
 
     grid = (n_idx // block,)
     return pl.pallas_call(

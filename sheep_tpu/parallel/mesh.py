@@ -15,28 +15,10 @@ from typing import Optional
 
 import jax
 import numpy as np
+from jax import shard_map  # noqa: F401 — the sharded pipeline's import point
 from jax.sharding import Mesh
 
 SHARD_AXIS = "shards"
-
-try:  # jax >= 0.5 promoted shard_map to the top-level namespace
-    from jax import shard_map as _jax_shard_map
-
-    _SHARD_MAP_COMPAT_KW: dict = {}
-except ImportError:  # pragma: no cover - older jaxlib
-    from jax.experimental.shard_map import shard_map as _jax_shard_map
-
-    # the experimental form has no replication rule for while_loop (the
-    # fixpoint segments' shape); check_rep=False skips the static check
-    # — every replicated output here really is replicated (pmax/pmin/
-    # psum results), so semantics are unchanged
-    _SHARD_MAP_COMPAT_KW = {"check_rep": False}
-
-
-def shard_map(f, **kw):
-    """``jax.shard_map`` across the jax versions this repo meets — the
-    single import point for the sharded pipeline and the bigv backend."""
-    return _jax_shard_map(f, **{**_SHARD_MAP_COMPAT_KW, **kw})
 
 
 def device_count() -> int:

@@ -913,8 +913,8 @@ def build_parser() -> argparse.ArgumentParser:
                         "staging)")
     p.add_argument("--inflight", type=int, default=None,
                    help="in-job dispatch pipeline depth: confirmed "
-                        "executions in flight per engine step (0 = "
-                        "auto: 1 on cpu, 2 on accelerators)")
+                        "executions in flight per engine step "
+                        "(default 1)")
     p.add_argument("--alpha", type=float, default=None)
     p.add_argument("--weights", choices=["unit", "degree"], default=None)
     p.add_argument("--comm-volume", action="store_true")

@@ -524,10 +524,8 @@ class Daemon:
 
     # -- lifecycle -----------------------------------------------------
     def serve(self) -> int:
-        from sheep_tpu.utils.platform import (enable_compilation_cache,
-                                              pin_platform)
+        from sheep_tpu.utils.platform import enable_compilation_cache
 
-        pin_platform()
         enable_compilation_cache()
         from sheep_tpu import obs
         from sheep_tpu.server.scheduler import Scheduler

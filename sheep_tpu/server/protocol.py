@@ -234,9 +234,9 @@ class JobSpec:
     ks: list
     tenant: str = "default"
     chunk_edges: int = 1 << 22
-    dispatch_batch: int = 0        # 0 = auto (membudget-sized)
+    dispatch_batch: int = 1        # chunks per batched fold program
     h2d_ring: int = 0              # 0 = auto (staged H2D ring depth)
-    inflight: int = 0              # 0 = auto (in-job pipeline depth)
+    inflight: int = 1              # in-job pipeline depth
     segment_rounds: int = 2
     alpha: float = 1.0
     weights: str = "unit"
@@ -280,9 +280,9 @@ class JobSpec:
         spec = cls(
             input=str(body["input"]), ks=ks, tenant=str(tenant),
             chunk_edges=int(body.get("chunk_edges", 1 << 22)),
-            dispatch_batch=int(body.get("dispatch_batch", 0)),
+            dispatch_batch=int(body.get("dispatch_batch", 1)),
             h2d_ring=int(body.get("h2d_ring", 0)),
-            inflight=int(body.get("inflight", 0)),
+            inflight=int(body.get("inflight", 1)),
             segment_rounds=int(body.get("segment_rounds", 2)),
             alpha=float(body.get("alpha", 1.0)),
             weights=str(body.get("weights", "unit")),
@@ -299,13 +299,12 @@ class JobSpec:
         )
         if spec.chunk_edges < 1:
             raise ProtocolError("job.chunk_edges must be >= 1")
-        if spec.dispatch_batch < 0:
-            raise ProtocolError("job.dispatch_batch must be >= 0 "
-                               "(0 = auto)")
+        if spec.dispatch_batch < 1:
+            raise ProtocolError("job.dispatch_batch must be >= 1")
         if spec.h2d_ring < 0:
             raise ProtocolError("job.h2d_ring must be >= 0 (0 = auto)")
-        if spec.inflight < 0:
-            raise ProtocolError("job.inflight must be >= 0 (0 = auto)")
+        if spec.inflight < 1:
+            raise ProtocolError("job.inflight must be >= 1")
         if spec.weights not in ("unit", "degree"):
             raise ProtocolError("job.weights must be 'unit' or 'degree'")
         if spec.deadline_s is not None and spec.deadline_s <= 0:

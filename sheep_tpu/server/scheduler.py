@@ -3,9 +3,8 @@
 The scheduler owns every job from submit to terminal state:
 
 **Admission (membudget-aware).** Each job's device footprint is
-modeled up front with the same HBM model the backends auto-size
-against (``utils/membudget.build_phase_bytes`` at the job's resolved
-dispatch batch). Against the daemon's budget (``SHEEP_CACHE_BYTES``
+modeled up front with the backends' HBM model
+(``utils/membudget.build_phase_bytes`` at the job's dispatch batch). Against the daemon's budget (``SHEEP_CACHE_BYTES``
 override, else 90% of reported HBM, else unlimited on cpu-jax):
 
 - a job that exceeds the WHOLE budget is first shed down the same
@@ -714,9 +713,8 @@ class Scheduler:
         invariant), so spilled mode trades only wall time for
         admission. Rejection remains only for jobs whose floor itself
         exceeds the budget."""
-        from sheep_tpu.backends.tpu_backend import (resolve_dispatch_batch,
-                                                    resolve_h2d_ring,
-                                                    resolve_inflight)
+        from sheep_tpu.backends.tpu_backend import (refused_dispatch_batch,
+                                                    resolve_h2d_ring)
         from sheep_tpu.io.devicestream import is_device_stream
         from sheep_tpu.io.edgestream import open_input
         from sheep_tpu.utils import membudget
@@ -732,9 +730,8 @@ class Scheduler:
         # the in-job pipeline (ISSUE 16) keeps D issued executions'
         # staging blocks live at once — admission must reserve them or
         # a full pipe re-creates the OOM churn it exists to prevent
-        infl = resolve_inflight(spec.inflight)
-        batch = resolve_dispatch_batch(spec.dispatch_batch, n, cs,
-                                       inflight=infl, h2d_ring=ring)
+        infl = spec.inflight
+        batch = spec.dispatch_batch
         if self.budget is None:
             return None, None, None, False
 
@@ -746,7 +743,8 @@ class Scheduler:
         m = total(batch)
         shed = None
         while m > self.budget:
-            nxt = membudget.degraded_dispatch(n, cs, batch, 1)
+            nxt = membudget.degraded_dispatch(
+                n, cs, batch, 1, refused_batch=refused_dispatch_batch())
             if nxt is None:
                 # spilled mode: the irreducible footprint — every
                 # overlap knob at 1, nothing resident (resident_bytes

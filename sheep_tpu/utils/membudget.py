@@ -33,8 +33,7 @@ def build_phase_bytes(n: int, chunk_edges: int, lift_levels: int = 0,
     ``dispatch_batch`` > 1 (the batched segment dispatch,
     ops/elim.py fold_segments_batch) additionally stages N segments on
     device at once: the raw (N, C, 2) chunk stack plus the oriented
-    [N, C] lo/hi blocks — the O(C) transient invariant becomes O(N*C),
-    which is exactly what :func:`dispatch_batch_for` sizes N against.
+    [N, C] lo/hi blocks — the O(C) transient invariant becomes O(N*C).
 
     ``inflight`` > 1 (the asynchronous dispatch pipeline,
     ops/elim.py fold_segments_pipelined) keeps D issued executions'
@@ -107,32 +106,10 @@ def build_phase_bytes(n: int, chunk_edges: int, lift_levels: int = 0,
     }
 
 
-def dispatch_batch_for(hbm_bytes: int, n: int, chunk_edges: int,
-                       cap: int = 16, inflight: int = 1,
-                       donate: bool = False, h2d_ring: int = 0) -> int:
-    """Largest power-of-two dispatch batch N in [1, cap] whose staged
-    build phase fits ``hbm_bytes`` — the ``--dispatch-batch 0`` (auto)
-    sizing rule. Power-of-two N keeps the set of compiled batch-program
-    shapes logarithmic, like every other buffer-sizing rule here.
-    ``inflight``/``donate``/``h2d_ring`` thread the in-flight
-    pipeline's staging multiplier, the donation credit and the staged
-    H2D ring into the model, so a deeper pipeline (or ring) auto-sizes
-    to a proportionally smaller N."""
-    best = 1
-    nb = 2
-    while nb <= cap:
-        if build_phase_bytes(n, chunk_edges, dispatch_batch=nb,
-                             inflight=inflight, donate=donate,
-                             h2d_ring=h2d_ring)["total_bytes"] > hbm_bytes:
-            break
-        best = nb
-        nb *= 2
-    return best
-
-
 def degraded_dispatch(n: int, chunk_edges: int, dispatch_batch: int,
                       inflight: int, donate: bool = False,
-                      h2d_ring=None, spillable_bytes: int = 0):
+                      h2d_ring=None, spillable_bytes: int = 0,
+                      refused_batch=None):
     """One RESOURCE_EXHAUSTED degradation step for the dispatch drivers
     (ISSUE 9): halve ``dispatch_batch``, ``inflight`` — or, when the
     caller runs a staged H2D ring (``h2d_ring`` given as an int >= 1,
@@ -155,9 +132,11 @@ def degraded_dispatch(n: int, chunk_edges: int, dispatch_batch: int,
     nothing left to spill does the ladder fall through to halving.
 
     Reusing :func:`build_phase_bytes` instead of a fixed halving order
-    keeps the degrade schedule consistent with the auto-sizing rule
-    (:func:`dispatch_batch_for`): the knob that the model says holds the
-    most staging is the knob an OOM most plausibly indicts."""
+    keeps the degrade schedule consistent with the HBM model: the knob
+    that the model says holds the most staging is the knob an OOM most
+    plausibly indicts. Halving the batch steps over ``refused_batch``
+    (a width the platform's compiler aborts on, see
+    ``backends/tpu_backend.TPU_REFUSED_BATCH``) to the next one down."""
     batch, depth = max(1, int(dispatch_batch)), max(1, int(inflight))
     ring = None if h2d_ring is None else max(1, int(h2d_ring))
     if spillable_bytes > 0:
@@ -174,8 +153,10 @@ def degraded_dispatch(n: int, chunk_edges: int, dispatch_batch: int,
     r0 = ring or 0
     cand = []
     if batch > 1:
-        cand.append((total(batch // 2, depth, r0),
-                     (batch // 2, depth, r0)))
+        half = batch // 2
+        if half == refused_batch:
+            half = max(1, half // 2)
+        cand.append((total(half, depth, r0), (half, depth, r0)))
     if depth > 1:
         cand.append((total(batch, depth // 2, r0),
                      (batch, depth // 2, r0)))

@@ -240,15 +240,17 @@ def degrade_dispatch(n: int, chunk_edges: int, batch: int, inflight: int,
     the first RESOURCE fault drops them — and halves the residency
     budget so refill pressure shrinks too — returning the dispatch
     knobs *unchanged*. Only a fault with nothing left to spill reaches
-    the halving rungs below."""
+    the halving rungs below, which never land on a batch width the
+    platform refuses (``tpu_backend.refused_dispatch_batch``)."""
     from sheep_tpu import obs
+    from sheep_tpu.backends.tpu_backend import refused_dispatch_batch
     from sheep_tpu.utils import membudget
 
     spillable = residency.spillable_bytes() if residency is not None \
         else 0
-    nxt = membudget.degraded_dispatch(n, chunk_edges, batch, inflight,
-                                      donate, h2d_ring=h2d_ring,
-                                      spillable_bytes=spillable)
+    nxt = membudget.degraded_dispatch(
+        n, chunk_edges, batch, inflight, donate, h2d_ring=h2d_ring,
+        spillable_bytes=spillable, refused_batch=refused_dispatch_batch())
     if nxt is not None and nxt[0] == "spill":
         freed = residency.pressure_spill()
         stats["spill_degrades"] = stats.get("spill_degrades", 0) + 1

@@ -26,7 +26,7 @@ from sheep_tpu.io.edgestream import EdgeStream
 from sheep_tpu.ops import degrees as degrees_ops
 from sheep_tpu.ops import elim as elim_ops
 from sheep_tpu.ops import order as order_ops
-from sheep_tpu.utils.membudget import build_phase_bytes, dispatch_batch_for
+from sheep_tpu.utils.membudget import build_phase_bytes, degraded_dispatch
 from sheep_tpu.utils.metrics import solve_dispatch_attribution
 
 
@@ -191,7 +191,7 @@ def test_solve_dispatch_attribution_exact():
 @pytest.mark.parametrize("db", [2, 4])
 def test_backend_dispatch_batch_bit_identical(db):
     """End-to-end TpuBackend equality: batched dispatch vs the default
-    per-segment driver (auto resolves to 1 on cpu-jax), multi-chunk
+    per-segment driver (auto resolves to 1), multi-chunk
     stream with a sentinel-padded tail group."""
     e = generators.rmat(11, 8, seed=9)
     n = 1 << 11
@@ -205,6 +205,85 @@ def test_backend_dispatch_batch_bit_identical(db):
     assert got.comm_volume == base.comm_volume
     assert got.diagnostics["dispatch_batch"] == db
     assert got.diagnostics["host_syncs"] > 0
+
+
+def test_default_dispatch_is_adaptive_on_an_accelerator(monkeypatch):
+    """The chip-only branches, taken on the CPU: with the platform
+    reported as ``tpu`` and a 16 GiB HBM, the default dispatch is the
+    adaptive per-segment driver (no batched executions, accelerator
+    host-tail handoff at C/2), bit-identical to the oracle (PR 21: on
+    a v5e the batched pipeline at N=16 took 313 rounds / 70.5 s at
+    RMAT-18 where the adaptive driver took 14 / 2.6 s); N=2, where the
+    TPU compiler aborts, is refused."""
+    from sheep_tpu.backends import tpu_backend as tb
+
+    monkeypatch.setattr(tb.jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(tb, "_device_hbm_bytes",
+                        lambda purpose="": 16 << 30)
+    e = generators.rmat(11, 8, seed=9)
+    n = 1 << 11
+    es = EdgeStream.from_array(e, n_vertices=n)
+    got = TpuBackend(chunk_edges=512).partition(es, 8)
+    ref = pure.partition_arrays(e, 8, n=n)
+    np.testing.assert_array_equal(got.assignment, ref.assignment)
+    assert "batch_execs" not in got.diagnostics
+    assert "dispatch_batch" not in got.diagnostics
+    with pytest.raises(ValueError, match="refused on tpu"):
+        TpuBackend(chunk_edges=512, dispatch_batch=2).partition(es, 8)
+    assert tb.check_dispatch_batch(4) == 4
+
+
+@pytest.mark.parametrize("caller", ["chunk_cache", "admission"])
+def test_hbm_budgets_on_an_accelerator(monkeypatch, capsys, caller):
+    """The chip-only HBM branches, taken on the CPU: a v5e that reports
+    no bytes_limit gets its 16 GiB from device_kind, for the chunk cache
+    and for sheepd's admission budget alike, each naming its own
+    override (a bad call here killed sheepd on the chip, PR 21)."""
+    from sheep_tpu.backends import tpu_backend as tb
+    from sheep_tpu.server import scheduler
+
+    class V5e:
+        device_kind = "TPU v5 lite"
+
+        def memory_stats(self):
+            return {}
+
+    monkeypatch.delenv("SHEEP_CACHE_BYTES", raising=False)
+    monkeypatch.setattr(tb.jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(tb.jax, "local_devices", lambda: [V5e()])
+    hbm = 16 << 30
+    if caller == "chunk_cache":
+        n, cs = 1 << 20, 1 << 16
+        want = int(0.9 * hbm) - build_phase_bytes(n, cs)["total_bytes"] \
+            - (1 << 30)
+        assert tb._chunk_cache_budget(n, cs) == want
+        assert "for the chunk cache" in capsys.readouterr().err
+    else:
+        assert scheduler.resolve_budget_bytes() == int(0.9 * hbm)
+        assert "for the admission budget" in capsys.readouterr().err
+
+
+@pytest.mark.parametrize("platform,start,want", [
+    ("tpu", 4, 1),     # steps over the refused N=2
+    ("tpu", 8, 4),
+    ("cpu", 4, 2),     # other platforms keep plain halving
+])
+def test_degrade_ladder_skips_refused_batch(monkeypatch, platform, start,
+                                            want):
+    """A RESOURCE fault halves the dispatch batch through
+    utils/retry.degrade_dispatch; on a TPU it never lands on N=2, the
+    width whose donated fold the compiler aborts the process on."""
+    from sheep_tpu.backends import tpu_backend as tb
+    from sheep_tpu.utils import retry
+
+    monkeypatch.setattr(tb.jax, "default_backend", lambda: platform)
+    stats = {}
+    nxt = retry.degrade_dispatch(1 << 22, 1 << 23, start, 1, True, stats, 0)
+    assert nxt == (want, 1)
+    assert stats["degraded_dispatch_batch"] == want
+    assert degraded_dispatch(1 << 22, 1 << 23, start, 1,
+                             refused_batch=tb.refused_dispatch_batch()) \
+        == (want, 1)
 
 
 def test_backend_dispatch_batch_excludes_tail_strategies():
@@ -237,18 +316,12 @@ def test_sharded_pipeline_dispatch_batch_matches():
 
 def test_membudget_staging_model():
     """The [N, C] staging blocks are counted (the O(C) transient
-    invariant becomes O(N*C)) and the auto-sizer returns the largest
-    power-of-two N that fits."""
+    invariant becomes O(N*C))."""
     n, cs = 1 << 20, 1 << 16
     base = build_phase_bytes(n, cs)
     b4 = build_phase_bytes(n, cs, dispatch_batch=4)
     assert b4["staging_bytes"] == 4 * 4 * cs * 4
     assert b4["total_bytes"] == base["total_bytes"] + b4["staging_bytes"]
-    exactly4 = build_phase_bytes(n, cs, dispatch_batch=4)["total_bytes"]
-    assert dispatch_batch_for(exactly4, n, cs) == 4
-    assert dispatch_batch_for(0, n, cs) == 1
-    big = build_phase_bytes(n, cs, dispatch_batch=1 << 10)["total_bytes"]
-    assert dispatch_batch_for(big, n, cs) == 16  # capped
 
 
 def test_cli_dispatch_batch_flag(tmp_path, capsys):

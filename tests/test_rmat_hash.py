@@ -44,6 +44,22 @@ def test_device_chunk_bit_identical_to_host_twin():
         assert np.all(d[len(h):] == n)  # sentinel padding
 
 
+def test_device_chunk_on_synthesizes_on_the_target_device():
+    """The multi-device placement hook computes each shard's chunk on
+    its own device: nothing is synthesized on (or left behind on)
+    device 0, and the bits equal the default-device chunk."""
+    import jax
+
+    devs = jax.devices()
+    assert len(devs) >= 4, "conftest should force virtual devices"
+    s = generators.RmatHashStream(10, 8, seed=3)
+    want = np.asarray(s.device_chunk(1, 1024, 1 << 10))
+    for dev in devs[1:4]:
+        got = s.device_chunk_on(dev, 1, 1024, 1 << 10)
+        assert got.devices() == {dev}
+        np.testing.assert_array_equal(np.asarray(got), want)
+
+
 def test_device_chunk_64bit_counter_carry():
     # a start index straddling the 2^32 boundary must hash the same as
     # the numpy twin (the device carries the counter as two uint32 words)

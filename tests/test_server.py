@@ -282,6 +282,30 @@ def test_job_fault_budget_exhaustion_fails_job_not_daemon(monkeypatch):
         assert ok.state == "done", ok.error
 
 
+def test_refused_dispatch_batch_fails_job_not_daemon(monkeypatch):
+    """A job asking for the dispatch batch the platform's compiler
+    aborts on (N=2 on a TPU, here reported so on the CPU) fails THAT
+    job before any fold compiles; the next request is served."""
+    from sheep_tpu.backends import tpu_backend as tb
+
+    monkeypatch.setattr(tb, "refused_dispatch_batch", lambda: 2)
+    with running_scheduler() as sched:
+        doomed = serve_one(sched, spec(tenant="doomed", dispatch_batch=2))
+        assert doomed.state == "failed"
+        assert "dispatch_batch=2 is refused" in doomed.error
+        ok = serve_one(sched, spec(tenant="after", dispatch_batch=4))
+        assert ok.state == "done", ok.error
+
+
+@pytest.mark.parametrize("field", ["dispatch_batch", "inflight"])
+def test_protocol_dispatch_knobs_default_to_one(field):
+    """The in-job dispatch knobs default to 1 (per-segment, synchronous)
+    and have no 0 = auto spelling."""
+    assert getattr(JobSpec.from_request({"input": "g", "k": 4}), field) == 1
+    with pytest.raises(ProtocolError, match=f"{field} must be >= 1"):
+        JobSpec.from_request({"input": "g", "k": 4, field: 0})
+
+
 def test_protocol_validation_and_codec():
     with pytest.raises(ProtocolError):
         JobSpec.from_request({"k": [4]})          # no input

@@ -53,6 +53,19 @@ def test_degrees_and_order_match_oracle(graph):
     assert int(pos[n]) == n and int(order[n]) == n
 
 
+def test_degree_chunk_matches_bincount():
+    """One scatter per endpoint column counts every endpoint: self-loops
+    twice, padding into slot n."""
+    n, chunk = 1 << 12, 1 << 10
+    e = np.random.default_rng(5).integers(0, n, (chunk - 7, 2))
+    e[:3, 1] = e[:3, 0]
+    deg = degrees_ops.degree_chunk(degrees_ops.init_degrees(n),
+                                   pad_chunk(e, chunk, n), n)
+    want = np.bincount(e.ravel(), minlength=n + 1)
+    want[n] = 2 * 7
+    np.testing.assert_array_equal(np.asarray(deg), want)
+
+
 @pytest.mark.parametrize("lift_levels", [1, 0])
 def test_fixpoint_tree_matches_oracle(graph, lift_levels):
     e, n = graph

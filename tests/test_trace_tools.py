@@ -218,8 +218,7 @@ def test_trace_report_attribution_degenerate(tmp_path):
 
 BASE = {"metric": "edges/sec partitioned (RMAT-20, k=64, tpu vs CPU)",
         "value": 1.0e6, "unit": "edges/sec", "vs_baseline": 2.0,
-        "r_colo_est": 2.4, "host_syncs": 10, "device_rounds": 40,
-        "rtt_ms": 5.0}
+        "host_syncs": 10, "device_rounds": 40, "device_gap_ms": 5.0}
 
 
 def _write(tmp_path, name, doc):
@@ -231,9 +230,9 @@ def _write(tmp_path, name, doc):
 def test_bench_regress_pass(tmp_path):
     old = _write(tmp_path, "old.json", {"n": 1, "parsed": BASE})
     new = _write(tmp_path, "new.json",
-                 {**BASE, "value": 1.05e6, "rtt_ms": 50.0})
+                 {**BASE, "value": 1.05e6, "device_gap_ms": 50.0})
     rc = bench_regress.main([new, old, "--threshold", "0.15"])
-    assert rc == 0, "faster run + environmental rtt swing is a pass"
+    assert rc == 0, "faster run + environmental idle swing is a pass"
 
 
 def test_bench_regress_detects_value_drop(tmp_path):

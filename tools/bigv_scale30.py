@@ -200,7 +200,8 @@ def main():
         "total_edges": int(big.total_edges),
         "balance": round(float(big.balance), 4),
         "phases": {p: round(s, 1) for p, s in big.phase_times.items()},
-        "diagnostics": {k: int(v) for k, v in big.diagnostics.items()},
+        "diagnostics": {k: (int(v) if isinstance(v, (int, float)) else v)
+                        for k, v in big.diagnostics.items()},
         "fixpoint_rounds": int(big.diagnostics["fixpoint_rounds"]),
         "peak_rss_gb": round(resource.getrusage(
             resource.RUSAGE_SELF).ru_maxrss / 1e6, 1),

@@ -1,7 +1,7 @@
 #!/usr/bin/env bash
 # Run a long CPU job that yields the (single) host core to TPU captures:
 # SIGSTOP the whole process group while tools/out/CAPTURING exists
-# (raised by tpu_watch2.sh), SIGCONT when it clears. The soak pipeline
+# (raised by whatever capture needs the core), SIGCONT when it clears. The soak pipeline
 # is checkpointed and kill-tolerant, so a pause is strictly safe.
 #
 # Auto-resume (ISSUE 9 satellite, ROADMAP item 5's dangling artifact):

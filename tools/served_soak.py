@@ -35,7 +35,7 @@ over a real on-disk graph (so the edgestream read points are live):
              reattach-idempotent failover resubmit, each forest
              bit-equal to the clean oracle.
 
-Per leg the verdict is exactly chaos_soak's taxonomy:
+Per leg the verdict is exactly chaos_soak's classification:
 
     identical            served assignment bit-equals the clean oracle
     degraded_documented  differs, but the job carries a documented
