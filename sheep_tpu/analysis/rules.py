@@ -6,9 +6,11 @@ deliberately HEURISTIC — linear statement-order taint with no fixpoint,
 no inter-procedural flow — tuned so that (a) every rule fires on the
 canonical bad pattern it exists for (pinned by tests/test_sheeplint.py
 fixtures) and (b) the current package audits clean without baselining,
-with the legitimate sync points carrying ``# sheeplint: sync-ok``
-pragmas that double as documentation and as the map of where the
-runtime sanitizer's ``sync_ok()`` windows belong. A heuristic this
+with the legitimate sync points reading through ``obs.pull`` (which
+opens the runtime sanitizer's ``sync_ok()`` window and names the pull
+on the profiler's trace) or carrying ``# sheeplint: sync-ok`` pragmas
+that double as documentation and as the map of where those windows
+belong. A heuristic this
 shape catches the regression that matters — someone inlining an
 ``int(sv[0])`` into a dispatch loop — without drowning the gate in
 false positives that would teach people to sprinkle pragmas blindly.
@@ -290,16 +292,17 @@ class _TaintScope:
                     + ("inside a jit'd function (breaks or "
                        "specializes the trace)" if self.in_jit else
                        "forces an implicit device->host sync; pull "
-                       "via np.asarray at an annotated sync point "
-                       "(# sheeplint: sync-ok) or keep it a future"))
+                       "via obs.pull(label, x) at a designed sync point "
+                       "or keep it a future"))
             elif _is_np_pull(node) and node.args \
                     and self.tainted(node.args[0]):
                 self.ctx.add(
                     rule, sev, node,
                     "np.asarray/np.array on a value from a jit'd call "
-                    "is a device->host pull; annotate the designed "
-                    "sync point with '# sheeplint: sync-ok' (and wrap "
-                    "it in sanitize.sync_ok() on guarded paths)")
+                    "is a device->host pull; make the designed sync "
+                    "point an obs.pull(label, x), or annotate it with "
+                    "'# sheeplint: sync-ok' (and wrap it in "
+                    "sanitize.sync_ok() on guarded paths)")
             elif isinstance(fn, ast.Attribute) \
                     and fn.attr in ("item", "tolist") \
                     and self.tainted(fn.value):

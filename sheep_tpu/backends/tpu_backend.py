@@ -26,7 +26,6 @@ import jax
 import jax.numpy as jnp
 
 from sheep_tpu import obs
-from sheep_tpu.analysis import sanitize
 from sheep_tpu.backends.base import Partitioner, register
 from sheep_tpu.io.devicestream import is_device_stream, note_device_chunks
 from sheep_tpu.ops import degrees as degrees_ops
@@ -525,11 +524,26 @@ class TpuBackend(Partitioner):
             stats["update_rounds"] = \
                 stats.get("update_rounds", 0) + int(rounds)
         # designed pull: the converged table is the update's product
-        state.minp = np.asarray(P[pos_dev])  # sheeplint: sync-ok
+        state.minp = obs.pull("update-table", P[pos_dev])
 
     def partition(self, stream, k: int, weights: str = "unit",
                   comm_volume: bool = True, checkpointer=None,
                   resume: bool = False, **opts) -> PartitionResult:
+        # ONE stats dict across all three streaming passes: the ingest
+        # counters (h2d_* / device_stream_chunks) accumulate wherever
+        # chunks cross (or don't cross) the link, and the build phase
+        # adds the dispatch counters to the same record. It is also the
+        # stats scope (obs.stats_scope): pulls, native calls and
+        # compiles anywhere below count into it
+        build_stats: dict = {}
+        with obs.stats_scope(build_stats):
+            return self._partition(stream, k, build_stats, weights,
+                                   comm_volume, checkpointer, resume,
+                                   **opts)
+
+    def _partition(self, stream, k: int, build_stats: dict, weights: str,
+                   comm_volume: bool, checkpointer, resume: bool,
+                   **opts) -> PartitionResult:
         from sheep_tpu.utils import checkpoint as ckpt
         from sheep_tpu.utils.fault import maybe_fail
 
@@ -582,11 +596,6 @@ class TpuBackend(Partitioner):
                                            donate=donate,
                                            h2d_ring=ring_model) \
             if self.cache_chunks else 0
-        # ONE stats dict across all three streaming passes: the ingest
-        # counters (h2d_* / device_stream_chunks) accumulate wherever
-        # chunks cross (or don't cross) the link, and the build phase
-        # adds the dispatch counters to the same record
-        build_stats: dict = {}
         # residency-managed chunk tier (ISSUE 20): same prefix-cache
         # fast path when the stream fits the budget, spill/reload with
         # checkpoint-boundary eviction when it does not — device memory
@@ -631,15 +640,13 @@ class TpuBackend(Partitioner):
                 if since_flush >= flush_every or at_ckpt:
                     # designed flush sync: int32 device accumulator ->
                     # int64 host totals
-                    deg_host += np.asarray(deg[:n],  # sheeplint: sync-ok
-                                           dtype=np.int64)
+                    deg_host += obs.pull("degrees-flush", deg[:n])
                     deg = degrees_ops.init_degrees(n)
                     since_flush = 0
                 if at_ckpt:
                     checkpointer.save("degrees", idx, {"deg": deg_host}, meta)
                     _ckpt_boundary(idx)
-            deg_host += np.asarray(deg[:n],  # sheeplint: sync-ok
-                                   dtype=np.int64)
+            deg_host += obs.pull("degrees-flush", deg[:n])
         t["degrees"] = time.perf_counter() - t0
         sp.end()
 
@@ -652,7 +659,7 @@ class TpuBackend(Partitioner):
             deg_dev = jnp.asarray(deg_rank, dtype=jnp.int32)
             pos, order = order_ops.elimination_order(deg_dev, n)
             # tiny host pull as the sort phase's completion barrier
-            np.asarray(pos[:1])  # sheeplint: sync-ok
+            obs.pull("sort-barrier", pos[:1])
             t["sort"] = time.perf_counter() - t0
         pos_host_cache = None
 
@@ -663,7 +670,7 @@ class TpuBackend(Partitioner):
         if state and from_phase >= 2:
             minp = jnp.asarray(state.arrays["minp"])
         else:
-            pos_host_cache = np.asarray(pos[:n])  # sheeplint: sync-ok
+            pos_host_cache = obs.pull("pos-host", pos[:n])
             tail_at = self.host_tail_threshold
             if tail_at < 0:
                 tail_at = cs // 2 if jax.default_backend() != "cpu" else 0
@@ -823,10 +830,10 @@ class TpuBackend(Partitioner):
                             # the sound cut for both the durable
                             # checkpoint and the in-memory retry
                             # snapshot
-                            with sanitize.sync_ok("flush-checkpoint"):
-                                arrays = {
-                                    "deg": deg_host,
-                                    "minp": np.asarray(tipP[pos])}  # sheeplint: sync-ok
+                            arrays = {
+                                "deg": deg_host,
+                                "minp": obs.pull("flush-checkpoint",
+                                                 tipP[pos])}
                             snap["idx"] = idx
                             snap["minp"] = arrays["minp"]
                             if checkpointer is not None:
@@ -915,12 +922,13 @@ class TpuBackend(Partitioner):
                                 if overlap:
                                     _flush_deltas()
                                 arrays = {"deg": deg_host,
-                                          "minp": np.asarray(P[pos])}
+                                          "minp": obs.pull("checkpoint",
+                                                           P[pos])}
                                 if carry_mode:
-                                    arrays["carry_lo"] = \
-                                        np.asarray(carry[0])
-                                    arrays["carry_hi"] = \
-                                        np.asarray(carry[1])
+                                    arrays["carry_lo"] = obs.pull(
+                                        "checkpoint", carry[0])
+                                    arrays["carry_hi"] = obs.pull(
+                                        "checkpoint", carry[1])
                                 snap["idx"] = idx
                                 snap["minp"] = arrays["minp"]
                                 if carry_mode:
@@ -1010,7 +1018,7 @@ class TpuBackend(Partitioner):
             ring_n = cfg["ring"]
             minp = P[pos]
             # real completion barrier (see above)
-            np.asarray(minp[:1])  # sheeplint: sync-ok
+            obs.pull("build-barrier", minp[:1])
         t["build"] = time.perf_counter() - t0
         stats_acc.absorb(build_stats)
         sp.end(fixpoint_rounds=int(total_rounds))
@@ -1019,7 +1027,7 @@ class TpuBackend(Partitioner):
         with obs.span("split"):
             parent = elim_ops.minp_to_parent(minp, order, n)
             pos_host = pos_host_cache if pos_host_cache is not None \
-                else np.asarray(pos[:n])  # sheeplint: sync-ok
+                else obs.pull("pos-host", pos[:n])
             w = deg_host.astype(np.float64) if weights == "degree" else None
             assign_host = split_ops.tree_split_host(parent, pos_host, k,
                                                     weights=w,
@@ -1046,8 +1054,8 @@ class TpuBackend(Partitioner):
                                      ring_n, build_stats):
             c, tt = score_ops.score_chunk(padded, assign, n)
             # designed per-chunk score pull (two scalars, one chunk)
-            cut += int(c)  # sheeplint: sync-ok
-            total += int(tt)  # sheeplint: sync-ok
+            cut += int(obs.pull("score-cut", c))
+            total += int(obs.pull("score-total", tt))
             if comm_volume:
                 score_ops.accumulate_cv_keys(
                     cv_chunks,
@@ -1058,7 +1066,8 @@ class TpuBackend(Partitioner):
             if checkpointer is not None and checkpointer.due(idx - start):
                 cv_chunks = ckpt.save_score_state(
                     checkpointer, idx, cut, total, cv_chunks,
-                    {"deg": deg_host, "minp": np.asarray(minp)}, meta,
+                    {"deg": deg_host, "minp": obs.pull("checkpoint", minp)},
+                    meta,
                     comm_volume)
                 _ckpt_boundary(idx)
         cv = int(len(ckpt.compact_cv_keys(cv_chunks))) if comm_volume else None

@@ -17,6 +17,7 @@ from __future__ import annotations
 
 import ctypes
 import fcntl
+import functools
 import hashlib
 import os
 import platform
@@ -153,10 +154,25 @@ def available() -> bool:
 
 # ---------------------------------------------------------------- wrappers
 
+def _timed(fn):
+    """Each call opens profiler span ``sheep/native:<fn>`` and adds its
+    wall to ``t_native_s`` of the current stats scope (``obs``)."""
+    from sheep_tpu import obs
+
+    name = "native:" + fn.__name__
+
+    @functools.wraps(fn)
+    def wrapper(*args, **kwargs):
+        with obs.timed(name, "t_native_s"):
+            return fn(*args, **kwargs)
+    return wrapper
+
+
 def _edges64(edges: np.ndarray) -> np.ndarray:
     return np.ascontiguousarray(np.asarray(edges).reshape(-1, 2), dtype=np.int64)
 
 
+@_timed
 def degrees(edges: np.ndarray, n: int, out: Optional[np.ndarray] = None) -> np.ndarray:
     lib = load()
     e = _edges64(edges)
@@ -166,6 +182,7 @@ def degrees(edges: np.ndarray, n: int, out: Optional[np.ndarray] = None) -> np.n
     return out
 
 
+@_timed
 def elim_order(deg: np.ndarray) -> np.ndarray:
     lib = load()
     d = np.ascontiguousarray(deg, dtype=np.int64)
@@ -174,6 +191,7 @@ def elim_order(deg: np.ndarray) -> np.ndarray:
     return pos
 
 
+@_timed
 def build_elim_tree(edges: np.ndarray, pos: np.ndarray,
                     parent: Optional[np.ndarray] = None) -> np.ndarray:
     lib = load()
@@ -187,6 +205,7 @@ def build_elim_tree(edges: np.ndarray, pos: np.ndarray,
     return parent
 
 
+@_timed
 def merge_trees(parent: np.ndarray, other: np.ndarray, pos: np.ndarray) -> np.ndarray:
     lib = load()
     parent = np.ascontiguousarray(parent, dtype=np.int64)
@@ -195,6 +214,7 @@ def merge_trees(parent: np.ndarray, other: np.ndarray, pos: np.ndarray) -> np.nd
     return parent
 
 
+@_timed
 def tree_split(parent: np.ndarray, pos: np.ndarray, k: int,
                weights: Optional[np.ndarray] = None, alpha: float = 1.0) -> np.ndarray:
     lib = load()

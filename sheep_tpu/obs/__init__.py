@@ -19,13 +19,28 @@ One module-level tracer that everything threads through:
 - **flight recorder** (ISSUE 11) — always-on bounded rings of the
   last N events per job, fed by :func:`event` alongside the tracer
   and dumped to the trace sink on failure/fault/shutdown
-  (``obs/flightrec.py``).
+  (``obs/flightrec.py``);
+- **profiler sink** — while a JAX profiler session runs (CLI
+  ``--profile-dir``, sheepd's ``profile`` verb, ``jax.profiler.
+  start_trace``), every span is also a ``jax.profiler.TraceAnnotation``
+  named ``sheep/<name>``, on the device ops' clock; finer spans that
+  live only there (``sheep/pull:<label>``, ``sheep/native:<fn>``,
+  ``sheep/fold``, ``sheep/compact``, ``sheep/host_tail``,
+  ``sheep/chunk_wait``) come from :func:`profiler_span`, :func:`pull`
+  and :func:`timed` (``obs/profiler.py``);
+- **stats scope** — :func:`stats_scope` makes a partition's stats dict
+  the target of the counters deep call sites add (:func:`pull`'s
+  ``pulls``/``pull_bytes``/``t_ready_wait_s``/``t_transfer_s``/
+  ``t_transfer_max_s``, :func:`timed`'s walls such as ``t_native_s``,
+  the compile listener's ``compiles``/``t_compile_s``) without the dict
+  being passed down; they reach the result's ``diagnostics``.
 
 Instrumentation calls are UNCONDITIONAL at the call sites (backends,
 pipelines, CLI) and near-free when tracing is off: every facade
-function reads one module global and returns a shared no-op. Install a
-tracer (CLI ``--trace``, or :func:`tracing` in tests/tools) and the
-same call sites produce the full trace.
+function reads one module global, asks whether a profiler session
+runs, and returns a shared no-op. Install a tracer (CLI ``--trace``, or
+:func:`tracing` in tests/tools) and the same call sites produce the
+full trace.
 
     from sheep_tpu import obs
 
@@ -48,6 +63,9 @@ from sheep_tpu.obs.flightrec import FlightRecorder  # noqa: F401
 from sheep_tpu.obs.heartbeat import Heartbeat  # noqa: F401
 from sheep_tpu.obs.manifest import collect_manifest, emit_manifest  # noqa: F401
 from sheep_tpu.obs.metrics import MetricRegistry  # noqa: F401
+from sheep_tpu.obs.profiler import (ProfiledSpan, add,  # noqa: F401
+                                    current_stats, profiled, profiler_span,
+                                    pull, stats_scope, timed)
 from sheep_tpu.obs.tracer import (NULL_SPAN, NULL_STATS, CounterRegistry,  # noqa: F401
                                   NullSpan, Span, StatsAccumulator, Tracer)
 
@@ -118,17 +136,17 @@ def enabled() -> bool:
 
 
 def span(name: str, **attrs):
-    """Context-manager span under the active tracer (shared no-op when
-    tracing is off)."""
+    """Context-manager span under the active tracer, and on the
+    profiler's trace while a session runs (shared no-op when neither)."""
     t = _TRACER
-    return t.span(name, **attrs) if t is not None else NULL_SPAN
+    return profiled(t.span(name, **attrs) if t is not None else NULL_SPAN,
+                    name)
 
 
 def begin(name: str, **attrs):
     """Explicitly-started span (``.end()`` when done) — the
     no-reindent form for instrumenting existing phase blocks."""
-    t = _TRACER
-    return t.begin(name, **attrs) if t is not None else NULL_SPAN
+    return span(name, **attrs).start()
 
 
 def begin_detached(name: str, parent=None, remote_parent=None, **attrs):
@@ -141,11 +159,12 @@ def begin_detached(name: str, parent=None, remote_parent=None, **attrs):
     ``{"trace": ..., "span": ...}`` (see Tracer.begin_detached)."""
     t = _TRACER
     if t is None:
-        return NULL_SPAN
-    if isinstance(parent, (Span, NullSpan)):
+        return profiled(NULL_SPAN, name).start()
+    if isinstance(parent, (Span, NullSpan, ProfiledSpan)):
         parent = getattr(parent, "id", None)
-    return t.begin_detached(name, parent=parent,
-                            remote_parent=remote_parent, **attrs)
+    return profiled(t.detached(name, parent=parent,
+                               remote_parent=remote_parent, **attrs),
+                    name).start()
 
 
 def current_span_id():

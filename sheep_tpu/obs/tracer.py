@@ -277,6 +277,13 @@ class Tracer:
         subtree under the originating client span in a DIFFERENT
         process's trace file. An all-zero remote span id means "the
         caller had no span of its own" — the trace id still lands."""
+        return self.detached(name, parent=parent,
+                             remote_parent=remote_parent, **attrs).start()
+
+    def detached(self, name: str, parent=None, remote_parent=None,
+                 **attrs) -> Span:
+        """The detached span :meth:`begin_detached` starts, not yet
+        started."""
         if remote_parent:
             attrs = dict(attrs)
             tid = remote_parent.get("trace")
@@ -285,8 +292,7 @@ class Tracer:
             rp = remote_parent.get("span")
             if rp and set(str(rp)) != {"0"}:
                 attrs.setdefault("remote_parent", str(rp))
-        return Span(self, name, attrs, parent=parent,
-                    attach=False).start()
+        return Span(self, name, attrs, parent=parent, attach=False)
 
     def current_span_id(self) -> Optional[int]:
         """The calling thread's innermost open span id (None at root)

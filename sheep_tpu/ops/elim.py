@@ -82,6 +82,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from sheep_tpu import obs
 from sheep_tpu.analysis import sanitize
 
 NO_PARENT = -1
@@ -769,14 +770,11 @@ def fold_segments_pipelined(
     def confirm(rec):
         """Blocking pull of one execution's stats word; returns done."""
         nonlocal total
-        t_pull = time.perf_counter()
         # the ONE designed sync of the pipeline: the one-behind packed
         # stats pull (everything else stays an unread future)
-        with sanitize.sync_ok("pipelined-sv-pull"):
-            done, r, live, retired = \
-                (int(x) for x in np.asarray(rec["sv"]))  # sheeplint: sync-ok
+        done, r, live, retired = (int(x) for x in obs.pull(
+            "pipelined-sv-pull", rec["sv"], host_blocked=stats))
         now = time.perf_counter()
-        _t_ms(stats, "host_blocked_ms", now - t_pull)
         stats["host_syncs"] = stats.get("host_syncs", 0) + 1
         stats["batch_execs"] = stats.get("batch_execs", 0) + 1
         stats["batch_retired"] = stats.get("batch_retired", 0) + retired
@@ -1117,18 +1115,18 @@ def _host_tail_finish_pos(P, loP, hiP, n: int, size: int, pos_host):
     is an existing test invariant)."""
     from sheep_tpu.core import native
 
-    clo, chi = compact_actives(loP, hiP, n, size, dedup=True)
+    with obs.profiler_span("compact"):
+        clo, chi = compact_actives(loP, hiP, n, size, dedup=True)
     # designed host-tail handoff: the compacted live constraints and
     # the O(V) table cross to the host exactly once per tail
-    with sanitize.sync_ok("host-tail-pull"):
-        lo_np = np.asarray(clo)  # sheeplint: sync-ok
-        hi_np = np.asarray(chi)  # sheeplint: sync-ok
+    lo_np = obs.pull("host-tail-lo", clo)
+    hi_np = obs.pull("host-tail-hi", chi)
     mask = lo_np != n
     pos_host = np.asarray(pos_host)
     order_host = _order_host(pos_host, n)
     edges = np.stack([order_host[lo_np[mask]], order_host[hi_np[mask]]],
                      axis=1)
-    P_np = np.asarray(P)  # the one O(V) device->host pull
+    P_np = obs.pull("host-tail-table", P)  # the one O(V) pull
     pp = P_np[pos_host]   # vertex-indexed parent positions
     parent = np.where(pp < n, order_host[np.minimum(pp, n)],
                       NO_PARENT).astype(np.int64)
@@ -1156,17 +1154,18 @@ def host_tail_delta(P_snap, loP, hiP, n: int, pos_host):
     Inputs must be HOST-safe snapshots (jax arrays are immutable, so the
     device arrays themselves are safe); everything here is numpy + the
     native core — no jax dispatch — making it executor-thread-friendly
-    apart from the initial np.asarray pulls."""
+    apart from the initial pulls."""
     from sheep_tpu.core import native
 
-    lo_np = np.asarray(loP)
-    hi_np = np.asarray(hiP)
+    lo_np = obs.pull("tail-overlap-lo", loP)
+    hi_np = obs.pull("tail-overlap-hi", hiP)
     mask = lo_np != n
     pos_host = np.asarray(pos_host)
     order_host = _order_host(pos_host, n)
     edges = np.stack([order_host[lo_np[mask]], order_host[hi_np[mask]]],
                      axis=1)
-    P_np = np.asarray(P_snap)  # O(V) pull overlapped with device work
+    # O(V) pull overlapped with device work
+    P_np = obs.pull("tail-overlap-table", P_snap)
     pp = P_np[pos_host]
     parent = np.where(pp < n, order_host[np.minimum(pp, n)],
                       NO_PARENT).astype(np.int64)
@@ -1222,9 +1221,14 @@ class TailOverlap:
 
     def submit(self, P, loP, hiP) -> None:
         """Queue a compacted live tail (device arrays are immutable, so
-        the P snapshot is safe to pull from the worker thread)."""
+        the P snapshot is safe to pull from the worker thread). The
+        worker runs in the caller's context: its pulls and native wall
+        count into the caller's stats scope."""
+        import contextvars
+
         self._pending.append(self._executor.submit(
-            host_tail_delta, P, loP, hiP, self.n, self.pos_host))
+            contextvars.copy_context().run, host_tail_delta, P, loP, hiP,
+            self.n, self.pos_host))
 
     def drain(self, block: bool) -> None:
         while self._pending and (block or self._pending[0].done()):
@@ -1333,6 +1337,8 @@ def _fold_adaptive_pos_impl_body(
             # need the stats); the in-flight batched pipeline is what
             # removes the window
             _t_ms(stats, "device_gap_ms", t0 - prev_ready)
+        # the segment's dispatch, as a span on the profiler's trace
+        fold_sp = obs.profiler_span("fold").start()
         if warm and size > small_size:
             wrounds, wlevels = warm.pop(0)
             seg = min(wrounds, max_rounds - total)
@@ -1377,18 +1383,16 @@ def _fold_adaptive_pos_impl_body(
                 P, loP, hiP, n, jumps=small_jumps, segment_rounds=seg)
             stats["small_segments"] = stats.get("small_segments", 0) + 1
             t_key = "t_small_s"
+        fold_sp.end()
         # ONE device pull per segment for all three control scalars
         # (each pull is a full host/device round-trip); the
         # duplicate collapse happens inside the dedup compactions, which
         # run rarely — a per-segment distinct count would cost a
         # full-buffer two-key sort every segment (measured: seconds at
         # C=2^24 on the v5e, swamping the rounds it saved)
-        t_pull = time.perf_counter()
-        with sanitize.sync_ok("adaptive-sv-pull"):
-            changed, r, live = \
-                (int(x) for x in np.asarray(sv))  # sheeplint: sync-ok
+        changed, r, live = (int(x) for x in obs.pull(
+            "adaptive-sv-pull", sv, host_blocked=stats))
         prev_ready = time.perf_counter()
-        _t_ms(stats, "host_blocked_ms", prev_ready - t_pull)
         # dispatch-count attribution: one host->device SYNC per segment
         # is this driver's cost shape (each sv pull is a full link
         # round-trip); the batched dispatch (fold_segments_batch) exists
@@ -1415,8 +1419,9 @@ def _fold_adaptive_pos_impl_body(
                 stats["carried_tails"] = stats.get("carried_tails", 0) + 1
                 stats["carried_live"] = stats.get("carried_live", 0) + live
                 cap = min(pow2_at_least(live, floor=1 << 14), size)
-                return P, total, compact_actives(loP, hiP, n, cap,
-                                                 dedup=True)
+                with obs.profiler_span("compact"):
+                    carry = compact_actives(loP, hiP, n, cap, dedup=True)
+                return P, total, carry
             if use_host_tail:
                 stats["host_tails"] = stats.get("host_tails", 0) + 1
                 stats["host_tail_live"] = \
@@ -1425,15 +1430,17 @@ def _fold_adaptive_pos_impl_body(
                 # the tail ships two O(size) arrays over the host link
                 pull = pow2_at_least(live, floor=1 << 14)
                 t0 = time.perf_counter()
-                out = _host_tail_finish_pos(P, loP, hiP, n,
-                                            min(pull, size), pos_host)
+                with obs.profiler_span("host_tail"):
+                    out = _host_tail_finish_pos(P, loP, hiP, n,
+                                                min(pull, size), pos_host)
                 t_add("t_host_tail_s", time.perf_counter() - t0)
                 return out, total, None
         if size > small_size and live <= size // 2:
             new_size = pow2_at_least(2 * live, floor=small_size)
             if new_size < size:
-                loP, hiP = compact_actives(loP, hiP, n, new_size,
-                                           dedup=True)
+                with obs.profiler_span("compact"):
+                    loP, hiP = compact_actives(loP, hiP, n, new_size,
+                                               dedup=True)
                 size = new_size
                 stats["compactions"] = stats.get("compactions", 0) + 1
 
@@ -1557,7 +1564,7 @@ def fold_edges_adaptive(
     if host_tail and pos_host is None and native.available():
         # only pulled when a host tail can actually run — this is an
         # O(V) d2h transfer
-        pos_host = np.asarray(pos[:n])  # sheeplint: sync-ok
+        pos_host = obs.pull("pos-host", pos[:n])
     P, total = fold_edges_adaptive_pos(
         minp[order], pos[lo], pos[hi], n, lift_levels=lift_levels,
         segment_rounds=segment_rounds, descent=descent,
@@ -1596,10 +1603,9 @@ def fold_edges_segmented(
             lo, hi, minp, changed, r = fold_edges_segment(
                 minp, lo, hi, pos, order, n, lift_levels=lift_levels,
                 segment_rounds=seg, descent=descent)
-            # the designed per-segment control pull of this driver
-            with sanitize.sync_ok("segmented-pull"):
-                total += int(r)  # sheeplint: sync-ok
-                done = not bool(changed)  # sheeplint: sync-ok
+            # the designed per-segment control pulls of this driver
+            total += int(obs.pull("segmented-pull", r))
+            done = not bool(obs.pull("segmented-pull", changed))
             if on_segment is not None:
                 on_segment(total)
             if done or total >= max_rounds:
@@ -1775,8 +1781,8 @@ def merge_forests(
 
 def minp_to_parent(minp, order, n):
     """minp encoding -> parent array (int64[n], -1 for roots) on host."""
-    minp = np.asarray(minp[:n])
-    order = np.asarray(order)
+    minp = obs.pull("split-table", minp[:n])
+    order = obs.pull("split-order", order)
     parent = np.where(minp < n, order[np.minimum(minp, n)], NO_PARENT)
     return parent.astype(np.int64)
 
@@ -1784,7 +1790,7 @@ def minp_to_parent(minp, order, n):
 def parent_to_minp(parent, pos, n):
     """parent array (int[n], -1 roots) -> device minp encoding int32[n+1]."""
     parent = np.asarray(parent)
-    pos = np.asarray(pos)
+    pos = obs.pull("pos-host", pos)
     minp = np.full(n + 1, n, dtype=np.int32)
     has = parent >= 0
     minp[:n][has] = pos[parent[has]]

@@ -163,19 +163,21 @@ def cut_pair_keys_host(chunk, assign, n: int, k: int):
     rows when they fit the capacity, the dense row dump otherwise."""
     import numpy as np
 
-    arr = np.asarray(chunk)
+    from sheep_tpu import obs
+
+    arr = obs.pull("cv-chunk", chunk)
     rows_all = []
     for c in arr.reshape(-1, arr.shape[-2], 2) if arr.ndim == 3 else [arr]:
         cap = _compact_cap(2 * c.shape[0])
         if cap < 2 * c.shape[0]:
             compact, count = cut_pair_rows_compact(c, assign, n, cap)
             # designed pulls: this helper IS the host accumulation step
-            if int(count) <= cap:  # sheeplint: sync-ok
-                rows = np.asarray(compact)  # sheeplint: sync-ok
+            if int(obs.pull("cv-count", count)) <= cap:
+                rows = obs.pull("cv-rows", compact)
                 rows = rows[rows[:, 0] < n]
                 rows_all.append(rows[:, 0].astype(np.int64) * k + rows[:, 1])
                 continue
-        rows = np.asarray(cut_pairs(c, assign, n))  # sheeplint: sync-ok
+        rows = obs.pull("cv-rows", cut_pairs(c, assign, n))
         rows = rows[rows[:, 0] < n]
         rows_all.append(rows[:, 0].astype(np.int64) * k + rows[:, 1])
     return np.concatenate(rows_all) if rows_all else np.zeros(0, np.int64)

@@ -40,6 +40,8 @@ import time
 from collections import deque
 from typing import Iterable, Iterator, TypeVar
 
+from sheep_tpu import obs
+
 T = TypeVar("T")
 
 _END = object()
@@ -66,8 +68,6 @@ class Prefetcher(Iterator[T]):
     def __init__(self, iterable: Iterable[T], depth: int = 2):
         if depth < 1:
             raise ValueError("prefetch depth must be >= 1")
-        from sheep_tpu import obs
-
         self._q: queue.Queue = queue.Queue(maxsize=depth)
         self._stop = threading.Event()
         self._closed = False
@@ -78,6 +78,9 @@ class Prefetcher(Iterator[T]):
         # pre-reading a served job's chunks land in that job's ring,
         # not the global one
         self._flight_job = obs.flight_job()
+        # and the stats scope (obs.stats_scope): counters the worker's
+        # reads add land in the partition that started it
+        self._stats = obs.current_stats()
         self._thread = threading.Thread(
             target=self._worker, args=(iterable,), daemon=True,
             name="sheep-prefetch")
@@ -95,9 +98,8 @@ class Prefetcher(Iterator[T]):
         return False
 
     def _worker(self, iterable) -> None:
-        from sheep_tpu import obs
-
-        with obs.flight_job_context(self._flight_job):
+        with obs.flight_job_context(self._flight_job), \
+                obs.stats_scope(self._stats):
             try:
                 for item in iterable:
                     if not self._put_until_stop(item):
@@ -281,6 +283,9 @@ class H2DRing:
       startup fill is attributed to staged, not blocked: before the
       first block there is no device work to overlap, the same
       convention ``device_gap_ms`` uses for the first dispatch.
+    - ``t_chunk_wait_s``  seconds of every blocking fill, startup
+      included: the whole time the consumer waited for its input (span
+      ``sheep/chunk_wait`` on the profiler's trace)
     - ``h2d_staged_bytes``  host bytes that crossed through the ring
     - ``h2d_ring_depth``    the resolved depth (gauge)
 
@@ -303,6 +308,7 @@ class H2DRing:
         self._stats.setdefault("h2d_staged_ms", 0.0)
         self._stats.setdefault("h2d_blocked_ms", 0.0)
         self._stats.setdefault("h2d_staged_bytes", 0)
+        self._stats.setdefault("t_chunk_wait_s", 0.0)
         self._stats["h2d_ring_depth"] = self.depth
         self._exhausted = False
         self._closed = False
@@ -341,10 +347,13 @@ class H2DRing:
         if not self._ring and not self._exhausted:
             # underrun (or startup): the consumer waits for host + issue
             # in its critical path — the tax the ring exists to hide
-            t0 = time.perf_counter()
-            self._fill(1, may_block=True)
+            with obs.profiler_span("chunk_wait"):
+                t0 = time.perf_counter()
+                self._fill(1, may_block=True)
+                dt = time.perf_counter() - t0
             key = "h2d_blocked_ms" if self._started else "h2d_staged_ms"
-            self._stats[key] += (time.perf_counter() - t0) * 1e3
+            self._stats[key] += dt * 1e3
+            self._stats["t_chunk_wait_s"] += dt
         if not self._ring:
             raise StopIteration
         self._started = True

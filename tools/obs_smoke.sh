@@ -419,13 +419,18 @@ grep -q "verdict: PASS" "$OUT/quality_gate.txt"
 # dir: the journaled job must RESUME from its per-job checkpoint (the
 # resume event on the record, rendered by trace_report), the restart
 # counters must be exported at /metrics, and --check must stay green
-# across the appended daemon runs.
+# across the appended daemon runs. The first daemon holds every fold
+# dispatch for SHEEP_FAULT_INJECT's stall (0.5 s each), so the job is
+# still building when the poller sees it at step 3, however fast the
+# host: the kill window does not race. The restarted daemon runs
+# without the stall.
 TRACE10="$OUT/trace_durable.jsonl"
 SOCK10="$OUT/sheepd_durable.sock"
 STATE10="$OUT/sheepd_state"
 rm -f "$TRACE10" "$SOCK10"
 rm -rf "$STATE10"
-JAX_PLATFORMS=cpu python -m sheep_tpu.server.daemon \
+JAX_PLATFORMS=cpu SHEEP_FAULT_INJECT=stall@dispatch:1:100000 \
+    python -m sheep_tpu.server.daemon \
     --socket "$SOCK10" --trace "$TRACE10" --heartbeat-secs 0.2 \
     --state-dir "$STATE10" --checkpoint-every 1 --metrics-port 0 \
     2> "$OUT/sheepd_durable.err" &
