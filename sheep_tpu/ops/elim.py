@@ -220,6 +220,11 @@ def _run_segment(body, P, loP, hiP, n: int, segment_rounds: int):
     return loP, hiP, P, stats
 
 
+def _stale_jump(t, cur, hi_):
+    cand = t[cur]
+    return jnp.where(cand < hi_, cand, cur)
+
+
 def _pos_round_body_stale(n: int, tables: tuple):
     """Round body for :func:`fold_segment_pos_hoisted`: identical
     retire/displace semantics to :func:`_pos_round_body` (exact
@@ -231,7 +236,13 @@ def _pos_round_body_stale(n: int, tables: tuple):
     non-maximal — ancestors; any progress missed is caught after the
     next rebuild. Saves (R-1)/R of the L x V squaring gathers per
     segment, the round's dominant V-term (BASELINE.md 'stale lifting
-    tables')."""
+    tables').
+
+    ``tables`` holds (table, live) pairs from :func:`build_lift_tables`.
+    A level whose table is all sentinel would gather ``n`` for every
+    slot, and ``n < hiP`` never holds, so its jump is skipped under a
+    ``lax.cond`` on ``live``: the same ``cur`` for none of the C-wide
+    gather's cost."""
 
     def body(state):
         lo_, hi_, P_, _, rounds = state
@@ -240,9 +251,9 @@ def _pos_round_body_stale(n: int, tables: tuple):
         now = newP[lo_]
 
         cur = lo_
-        for t in reversed(tables):
-            cand = t[cur]
-            cur = jnp.where(cand < hi_, cand, cur)
+        for t, live in reversed(tables):
+            cur = lax.cond(live, _stale_jump, lambda t_, c, h: c,
+                           t, cur, hi_)
         # level 0 last and CURRENT: guarantees one-step progress per
         # live slot even right after a displacement spawn
         cand = newP[cur]
@@ -278,7 +289,9 @@ def fold_segment_pos_hoisted(
     stack HOISTED out of the round loop: tables t_1..t_{L-1} are built
     once from the entry table and stay fixed for the whole segment;
     only level 0 (the table itself) is current inside rounds. Same
-    (loP, hiP, P, stats) contract. The final forest is the same unique
+    (loP, hiP, P, stats) contract, with a fourth stats entry: the
+    number of stale levels that are live (:func:`fold_segment_pos_stale`).
+    The final forest is the same unique
     fixpoint (stale jumps are sound, see :func:`_pos_round_body_stale`);
     per-round trajectories may differ from the fresh-table body, so the
     adaptive driver treats round counts as diagnostics, not contracts.
@@ -297,13 +310,22 @@ def build_lift_tables(P: jax.Array, n: int, lift_levels: int = 0):
     """The exact-descent lifting stack t_1..t_{L-1} as a standalone
     program, for CROSS-SEGMENT reuse (``stale_reuse`` > 1 in the
     adaptive driver): (L-1) x V squaring gathers once per rebuild
-    instead of once per segment."""
+    instead of once per segment.
+
+    Returns (table, live) pairs, ``live`` a device bool: the table
+    holds some ancestor other than the sentinel ``n``. The forest is
+    usually shallower than 2^(L-1) (at V = 2^20 a Graph500 forest's
+    tables are all sentinel after 9-17 squarings of 20), and an
+    all-sentinel table squares to itself, so past the first dead level
+    each squaring is a ``lax.cond`` pass-through, not a V-wide gather."""
     lift_levels, _ = _resolve(n, lift_levels, "exact")
     t = P.astype(jnp.int32)
+    live = jnp.any(t != n)
     tables = []
     for _ in range(lift_levels - 1):
-        t = t[t]
-        tables.append(t)
+        t = lax.cond(live, lambda x: x[x], lambda x: x, t)
+        live = jnp.any(t != n)
+        tables.append((t, live))
     return tuple(tables)
 
 
@@ -326,9 +348,16 @@ def fold_segment_pos_stale(
     freshness, because slots only change toward progress and the table
     only changes through a retiring slot (see _pos_round_body). Stale
     jumps land on genuine ancestors (permanence), so the unique
-    fixpoint is unchanged; only round counts differ."""
+    fixpoint is unchanged; only round counts differ.
+
+    ``stats`` is int32[4]: :func:`_run_segment`'s (changed, rounds,
+    live) and the number of live stale levels, pulled in the same
+    packed read."""
     body = _pos_round_body_stale(n, tuple(tables))
-    return _run_segment(body, P, loP, hiP, n, segment_rounds)
+    loP, hiP, P, stats = _run_segment(body, P, loP, hiP, n, segment_rounds)
+    levels = sum((live.astype(jnp.int32) for _, live in tables),
+                 jnp.int32(0))
+    return loP, hiP, P, jnp.concatenate([stats, jnp.reshape(levels, (1,))])
 
 
 @partial(jax.jit, static_argnames=("n", "lift_levels", "segment_rounds",
@@ -1390,9 +1419,17 @@ def _fold_adaptive_pos_impl_body(
         # run rarely — a per-segment distinct count would cost a
         # full-buffer two-key sort every segment (measured: seconds at
         # C=2^24 on the v5e, swamping the rounds it saved)
-        changed, r, live = (int(x) for x in obs.pull(
-            "adaptive-sv-pull", sv, host_blocked=stats))
+        sv = obs.pull("adaptive-sv-pull", sv, host_blocked=stats)
         prev_ready = time.perf_counter()
+        changed, r, live = (int(x) for x in sv[:3])
+        if len(sv) > 3:
+            # a stale-table segment (L = rl levels): its fourth entry
+            # counts the lifting levels that were not all sentinel
+            levels = int(sv[3])
+            stats["lift_levels_live"] = \
+                stats.get("lift_levels_live", 0) + levels
+            stats["lift_levels_skipped"] = \
+                stats.get("lift_levels_skipped", 0) + rl - 1 - levels
         # dispatch-count attribution: one host->device SYNC per segment
         # is this driver's cost shape (each sv pull is a full link
         # round-trip); the batched dispatch (fold_segments_batch) exists

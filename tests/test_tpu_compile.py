@@ -85,6 +85,22 @@ def test_segment_fold_batch_donated_compiles(one_chip):
     assert ma.alias_size_in_bytes >= (V + 1 + 2 * B * C) * 4
 
 
+def test_hoisted_fold_compiles_at_the_batch_cell_shape(one_chip):
+    """The tpu backend's full-width segment (ops/elim.py
+    fold_segment_pos_hoisted) at the Graph500 scale-20 cell's shape,
+    V = 2^20 and C = 2^22: the all-sentinel levels' conds compile, the
+    20-table stack fits HBM, and the stats word carries the live-level
+    count."""
+    from sheep_tpu.ops import elim
+
+    v, c = 1 << 20, 1 << 22
+    compiled = elim.fold_segment_pos_hoisted.lower(
+        _sds((v + 1,), one_chip), _sds((c,), one_chip), _sds((c,), one_chip),
+        v, lift_levels=0, segment_rounds=2).compile()
+    _fits(compiled)
+    assert compiled.out_info[3].shape == (4,)
+
+
 @pytest.mark.parametrize("program", ["score_chunk", "orient_batch"])
 def test_chunk_programs_compile(one_chip, program):
     """Score (ops/score.py) and the batch orient that stages the fold's

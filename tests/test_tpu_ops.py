@@ -504,6 +504,113 @@ def test_stale_reuse_rebuild_cadence():
     assert stats.get("stack_rebuilds", 0) == -(-full // 3)
 
 
+def _unskipped_stale_segment(n, lift_levels, segment_rounds):
+    """The hoisted segment as it was before all-sentinel levels were
+    skipped: every one of the L-1 squarings and every stale level's
+    gather runs. The reference for the skip's bit-identity."""
+    import jax
+    from jax import lax
+
+    @jax.jit
+    def run(P, loP, hiP):
+        tables = [P]
+        for _ in range(lift_levels - 1):
+            tables.append(tables[-1][tables[-1]])
+
+        def body(state):
+            lo_, hi_, P_, _, rounds = state
+            old_at_lo = P_[lo_]
+            newP = P_.at[lo_].min(hi_, mode="drop")
+            now = newP[lo_]
+            cur = lo_
+            for t in reversed(tables[1:]):
+                cand = t[cur]
+                cur = jnp.where(cand < hi_, cand, cur)
+            cand = newP[cur]
+            cur = jnp.where(cand < hi_, cand, cur)
+            became_loop = cur == hi_
+            climb_lo = jnp.where(became_loop, n, cur)
+            climb_hi = jnp.where(became_loop, n, hi_)
+            retire = hi_ == now
+            displaced = retire & (now < old_at_lo) & (old_at_lo < n)
+            out_lo = jnp.where(retire, jnp.where(displaced, now, n),
+                               climb_lo).astype(jnp.int32)
+            out_hi = jnp.where(retire, jnp.where(displaced, old_at_lo, n),
+                               climb_hi).astype(jnp.int32)
+            changed = jnp.any((out_lo != lo_) | (out_hi != hi_))
+            return out_lo, out_hi, newP, changed, rounds + 1
+
+        def cond(state):
+            return state[3] & (state[4] < segment_rounds)
+
+        lo_, hi_, P_, changed, rounds = lax.while_loop(
+            cond, body, (loP, hiP, P, jnp.bool_(True), jnp.int32(0)))
+        return lo_, hi_, P_, jnp.stack([changed.astype(jnp.int32), rounds,
+                                        jnp.sum(lo_ != n, dtype=jnp.int32)])
+
+    return run
+
+
+def test_hoisted_skip_is_bit_identical_segment_by_segment():
+    """Skipping the all-sentinel lifting levels (elim.py
+    build_lift_tables / _pos_round_body_stale) changes no segment's
+    output: from the same entry state, the hoisted program returns the
+    same (loP, hiP, P) and (changed, rounds, live) as the un-skipped
+    stale body, on every segment of a stream of RMAT chunks, and its
+    fourth stats entry counts the tables that are not all sentinel."""
+    e, n = generators.rmat(10, 8, seed=3), 1024
+    pos, _ = _device_order(e, n)
+    L = n.bit_length()
+    ref = _unskipped_stale_segment(n, L, 2)
+    P = jnp.full(n + 1, n, dtype=jnp.int32)
+    chunk = 1024
+    levels_seen = set()
+    for off in range(0, len(e), chunk):
+        loP, hiP = elim_ops.orient_edges_pos(
+            jnp.asarray(pad_chunk(e[off:off + chunk], chunk, n)), pos, n)
+        while True:
+            got = elim_ops.fold_segment_pos_hoisted(P, loP, hiP, n,
+                                                    segment_rounds=2)
+            want = ref(P, loP, hiP)
+            for name, x, y in zip(("loP", "hiP", "P", "stats"), got, want):
+                np.testing.assert_array_equal(
+                    np.asarray(x)[:3] if name == "stats" else np.asarray(x),
+                    np.asarray(y), err_msg=f"{name} diverged")
+            t = np.asarray(P)
+            depth = 0  # tables t_1..t_{L-1} that hold a real ancestor
+            for _ in range(L - 1):
+                t = t[t]
+                depth += bool(np.any(t != n))
+            sv = np.asarray(got[3])
+            assert sv[3] == depth
+            levels_seen.add(int(sv[3]))
+            loP, hiP, P = got[:3]
+            if not sv[0] or sv[2] == 0:
+                break
+    assert levels_seen & set(range(1, L - 1)), \
+        "the stream must hold segments with some levels skipped, some not"
+
+
+@pytest.mark.parametrize("stale_reuse", [1, 3])
+def test_lift_level_counters_cover_every_full_segment(stale_reuse):
+    """The adaptive driver sums the stale programs' live-level entry:
+    live + skipped = full segments x (L-1), and a fold from an empty
+    table skips levels (its first stack is all sentinel)."""
+    e, n = _cases()["rmat"]
+    pos, _ = _device_order(e, n)
+    loP, hiP = elim_ops.orient_edges_pos(
+        jnp.asarray(pad_chunk(e, len(e), n)), pos, n)
+    stats: dict = {}
+    elim_ops.fold_edges_adaptive_pos(
+        jnp.full(n + 1, n, dtype=jnp.int32), loP, hiP, n, segment_rounds=2,
+        small_size=8, host_tail=False, stale_reuse=stale_reuse, stats=stats)
+    full = stats.get("full_segments", 0)
+    assert full > 0
+    assert stats["lift_levels_skipped"] > 0
+    assert stats["lift_levels_live"] + stats["lift_levels_skipped"] == \
+        full * (n.bit_length() - 1)
+
+
 def test_fold_stats_wall_attribution():
     """Every segment kind executed must leave its t_* wall key in
     stats, each key non-negative and summing to (well under) the call's
