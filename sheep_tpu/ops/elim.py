@@ -386,6 +386,53 @@ def fold_segment_pos(
     return _run_segment(body, P, loP, hiP, n, segment_rounds)
 
 
+def _collapse_duplicates(lo: jax.Array, hi: jax.Array, n: int):
+    """Sort (lo, hi) on both keys and set every repeat of a live pair to
+    the inert sentinel (n, n); the width stays. Returns the collapsed
+    pair and the number of live slots it set to sentinel. Exact: the
+    fixpoint is a function of the constraint SET (duplicates retire
+    together and spawn identical displacements)."""
+    lo, hi = lax.sort((lo, hi), num_keys=2)
+    dup = (lo == jnp.roll(lo, 1)) & (hi == jnp.roll(hi, 1)) & (lo != n)
+    dup = dup.at[0].set(False)
+    return (jnp.where(dup, n, lo), jnp.where(dup, n, hi),
+            jnp.sum(dup, dtype=jnp.int32))
+
+
+@partial(jax.jit, static_argnames=("n", "lift_levels", "segment_rounds",
+                                   "descent"))
+def fold_segment_pos_collapse(
+    P: jax.Array,
+    loP: jax.Array,
+    hiP: jax.Array,
+    threshold,
+    n: int,
+    lift_levels: int = 0,
+    segment_rounds: int = 32,
+    descent: str = "auto",
+):
+    """:func:`fold_segment_pos`'s rounds, then, in the same program, the
+    duplicate constraints collapsed in place when more than
+    ``threshold`` slots are live (a traced scalar: one program for every
+    threshold). The adaptive driver's warm segment: after the first
+    cheap rounds of a chunk many slots hold the same (ancestor, hi)
+    constraint, and the driver's host-tail and compaction decisions are
+    made on what the fixpoint still has to resolve, the distinct count.
+    A segment already under the threshold skips the sort.
+
+    ``stats`` is int32[4]: (changed, rounds, live, dropped), ``live``
+    counted after the collapse and ``dropped`` the live slots it set to
+    sentinel, so the count rides the segment's one packed pull."""
+    loP, hiP, P, stats = fold_segment_pos(
+        P, loP, hiP, n, lift_levels=lift_levels,
+        segment_rounds=segment_rounds, descent=descent)
+    loP, hiP, dropped = lax.cond(
+        stats[2] > threshold, partial(_collapse_duplicates, n=n),
+        lambda lo, hi: (lo, hi, jnp.int32(0)), loP, hiP)
+    return loP, hiP, P, jnp.concatenate(
+        [stats.at[2].add(-dropped), jnp.reshape(dropped, (1,))])
+
+
 def _pos_small_round_body(n: int, jumps: int):
     """Jump-mode round body for SMALL active buffers: identical
     retire/displace semantics to :func:`_pos_round_body`, but the climb is
@@ -1089,21 +1136,19 @@ def compact_actives(lo: jax.Array, hi: jax.Array, n: int, size: int,
     dedup are exact.
 
     ``dedup`` additionally drops duplicate (lo, hi) pairs first via one
-    two-key sort: after a few rounds many slots have been rewritten to
-    the same (ancestor, hi) constraint. The production driver sizes the
-    target from the cheap pre-dedup live count, which every segment
-    program returns in its packed stats vector (:func:`fold_segment_pos`)
-    — a per-segment distinct count would cost a full-buffer sort each
-    segment (measured: seconds at C=2^24 on the v5e). The live count is
+    two-key sort (:func:`_collapse_duplicates`): after a few rounds many
+    slots have been rewritten to the same (ancestor, hi) constraint. The
+    production driver sizes the target from the live count in the
+    segment's packed stats vector. After a chunk's warm segment that
+    count is already the distinct count: the warm program collapses the
+    duplicates once a chunk (:func:`fold_segment_pos_collapse`), where
+    most of them arise. After a full segment it counts every live slot,
     an upper bound on the distinct count, so the size is always
-    sufficient. :func:`count_live_distinct` exists for
-    diagnostics/tests."""
+    sufficient; a distinct count after every segment would cost a
+    full-buffer sort each segment. :func:`count_live_distinct` exists
+    for diagnostics/tests."""
     if dedup:
-        lo, hi = lax.sort((lo, hi), num_keys=2)
-        dup = (lo == jnp.roll(lo, 1)) & (hi == jnp.roll(hi, 1))
-        dup = dup.at[0].set(False)
-        lo = jnp.where(dup, n, lo)
-        hi = jnp.where(dup, n, hi)
+        lo, hi, _ = _collapse_duplicates(lo, hi, n)
     c = lo.shape[0]
     # fill slots index an appended sentinel row, so padding is inert
     sel = jnp.nonzero(lo != n, size=size, fill_value=c)[0]
@@ -1114,11 +1159,9 @@ def compact_actives(lo: jax.Array, hi: jax.Array, n: int, size: int,
 
 @partial(jax.jit, static_argnames=("n",))
 def count_live_distinct(lo: jax.Array, hi: jax.Array, n: int):
-    slo, shi = lax.sort((lo, hi), num_keys=2)
-    dup = (slo == jnp.roll(slo, 1)) & (shi == jnp.roll(shi, 1))
-    dup = dup.at[0].set(False)
-    live = jnp.sum(slo != n)
-    return live, live - jnp.sum(dup & (slo != n))
+    _, _, dropped = _collapse_duplicates(lo, hi, n)
+    live = jnp.sum(lo != n)
+    return live, live - dropped
 
 
 
@@ -1371,8 +1414,8 @@ def _fold_adaptive_pos_impl_body(
         if warm and size > small_size:
             wrounds, wlevels = warm.pop(0)
             seg = min(wrounds, max_rounds - total)
-            loP, hiP, P, sv = fold_segment_pos(
-                P, loP, hiP, n, lift_levels=wlevels,
+            loP, hiP, P, sv = fold_segment_pos_collapse(
+                P, loP, hiP, host_tail_threshold, n, lift_levels=wlevels,
                 segment_rounds=seg, descent="stream")
             stats["warm_segments"] = stats.get("warm_segments", 0) + 1
             t_key = "t_warm_s"
@@ -1413,16 +1456,21 @@ def _fold_adaptive_pos_impl_body(
             stats["small_segments"] = stats.get("small_segments", 0) + 1
             t_key = "t_small_s"
         fold_sp.end()
-        # ONE device pull per segment for all three control scalars
-        # (each pull is a full host/device round-trip); the
-        # duplicate collapse happens inside the dedup compactions, which
-        # run rarely — a per-segment distinct count would cost a
-        # full-buffer two-key sort every segment (measured: seconds at
-        # C=2^24 on the v5e, swamping the rounds it saved)
+        # ONE device pull per segment for all its control scalars
+        # (each pull is a full host/device round-trip). A warm segment
+        # ends in the chunk's one duplicate collapse, so its live count
+        # is the distinct count and the host-tail and compaction
+        # decisions below see what the fixpoint still has to resolve;
+        # after other segments live counts every live slot (a distinct
+        # count there would cost a full-buffer sort every segment)
         sv = obs.pull("adaptive-sv-pull", sv, host_blocked=stats)
         prev_ready = time.perf_counter()
         changed, r, live = (int(x) for x in sv[:3])
-        if len(sv) > 3:
+        if t_key == "t_warm_s":
+            # the live slots the collapse set to sentinel
+            stats["warm_duplicates"] = \
+                stats.get("warm_duplicates", 0) + int(sv[3])
+        elif len(sv) > 3:
             # a stale-table segment (L = rl levels): its fourth entry
             # counts the lifting levels that were not all sentinel
             levels = int(sv[3])
@@ -1516,7 +1564,11 @@ def fold_edges_adaptive_pos(
       full-buffer round's cost is ~linear in lift_levels x buffer width,
       and the bulk of the buffer retires in the first rounds without
       needing long jumps, so cheap warm rounds + compaction shrink the
-      buffer before any full-depth round pays for it
+      buffer before any full-depth round pays for it. Each warm segment
+      ends by collapsing duplicate constraints when more than
+      ``host_tail_threshold`` slots are live
+      (:func:`fold_segment_pos_collapse`), so the decisions below see
+      the distinct count
     - full mode: lifting-table segments on the current buffer
     - after each segment, if live count <= size/2, compact the buffer to
       max(small_size, 2*live) rounded up to a power of two (each size is

@@ -101,6 +101,24 @@ def test_hoisted_fold_compiles_at_the_batch_cell_shape(one_chip):
     assert compiled.out_info[3].shape == (4,)
 
 
+def test_warm_collapse_fold_compiles_at_the_batch_cell_shape(one_chip):
+    """The tpu backend's warm segment (ops/elim.py
+    fold_segment_pos_collapse) at the same shape, V = 2^20 and
+    C = 2^22, with the cell's warm schedule (one round, 8 levels,
+    stream descent): the rounds and the two-key sort of the duplicate
+    collapse under its cond compile as one program, the threshold is a
+    traced scalar, and the stats word carries the dropped count."""
+    from sheep_tpu.ops import elim
+
+    v, c = 1 << 20, 1 << 22
+    compiled = elim.fold_segment_pos_collapse.lower(
+        _sds((v + 1,), one_chip), _sds((c,), one_chip), _sds((c,), one_chip),
+        _sds((), one_chip), v, lift_levels=8, segment_rounds=1,
+        descent="stream").compile()
+    _fits(compiled)
+    assert compiled.out_info[3].shape == (4,)
+
+
 @pytest.mark.parametrize("program", ["score_chunk", "orient_batch"])
 def test_chunk_programs_compile(one_chip, program):
     """Score (ops/score.py) and the batch orient that stages the fold's

@@ -611,6 +611,110 @@ def test_lift_level_counters_cover_every_full_segment(stale_reuse):
         full * (n.bit_length() - 1)
 
 
+def _uncollapsed_warm_segment(P, loP, hiP, threshold, n, **kw):
+    """The warm segment as it was before it collapsed duplicates: the
+    rounds alone, nothing set to sentinel, every live slot counted. The
+    reference for the collapse's bit-identity."""
+    loP, hiP, P, sv = elim_ops.fold_segment_pos(P, loP, hiP, n, **kw)
+    return loP, hiP, P, jnp.concatenate([sv, jnp.zeros(1, jnp.int32)])
+
+
+@pytest.mark.parametrize("case", ["distinct_under", "distinct_over",
+                                  "live_under", "carry", "compact"])
+def test_warm_collapse_decides_on_the_distinct_count(case, monkeypatch):
+    """The warm segment collapses duplicate constraints when more slots
+    are live than the host-tail threshold (elim.py
+    fold_segment_pos_collapse), and the adaptive driver decides on the
+    distinct count. An RMAT stream in chunks of 2^14: its first chunk
+    leaves many duplicates after the warm round, a later chunk few.
+    distinct_under: distinct <= threshold < live, so the host tail is
+    taken straight after the warm segment, where the uncollapsed
+    driver ran full segments; carry: the same in carry-out mode, so
+    the distinct constraints are carried straight after it; compact:
+    a first chunk of 2^15 with threshold < distinct <= C/4 < C/2 <
+    live, so the size//2 compaction runs straight after the warm
+    segment, sized by the distinct count; distinct_over: a later chunk
+    with distinct > threshold runs the full segments it ran before;
+    live_under: live <= threshold, so nothing is collapsed. The forest
+    is bit-identical to the uncollapsed driver's in every case, and
+    ``warm_duplicates`` counts live - distinct."""
+    e, n = generators.rmat(12, 16, seed=1), 1 << 12
+    pos, _ = _device_order(e, n)
+    pos_host = np.asarray(pos[:n])
+    c = 1 << (15 if case == "compact" else 14)
+    opts = dict(segment_rounds=2, warm_schedule=((1, 8),),
+                small_size=1 << 10, pos_host=pos_host)
+    P = jnp.full(n + 1, n, dtype=jnp.int32)
+    chunk = 0 if case != "distinct_over" else 1
+    for i in range(chunk + 1):
+        loP, hiP = elim_ops.orient_edges_pos(
+            jnp.asarray(pad_chunk(e[i * c:(i + 1) * c], c, n)), pos, n)
+        if i < chunk:
+            P, _ = elim_ops.fold_edges_adaptive_pos(P, loP, hiP, n, **opts)
+    wlo, whi, _, _ = elim_ops.fold_segment_pos(
+        P, loP, hiP, n, lift_levels=8, segment_rounds=1, descent="stream")
+    live, distinct = (int(x) for x in
+                      elim_ops.count_live_distinct(wlo, whi, n))
+    threshold = {"distinct_under": (live + distinct) // 2,
+                 "carry": (live + distinct) // 2,
+                 "compact": distinct // 2,
+                 "distinct_over": distinct - 1, "live_under": live}[case]
+    if case in ("distinct_under", "carry"):
+        assert distinct <= threshold < live
+    elif case == "compact":
+        assert threshold < distinct <= c // 4 and c // 2 < live
+    elif case == "distinct_over":
+        assert c // 2 < distinct - 1 == threshold < live
+
+    def fold(stats):
+        return elim_ops.fold_edges_adaptive_pos(
+            P, loP, hiP, n, host_tail_threshold=threshold, stats=stats,
+            **opts)[0]
+
+    got: dict = {}
+    compactions = []
+    real_compact = elim_ops.compact_actives
+
+    def spy(lo, hi, n, size, **kw):
+        compactions.append((lo.shape[0], size))
+        return real_compact(lo, hi, n, size, **kw)
+
+    with monkeypatch.context() as m:
+        m.setattr(elim_ops, "compact_actives", spy)
+        if case == "carry":
+            P_got, _, carry = elim_ops.fold_edges_adaptive_pos_carry(
+                P, loP, hiP, n, host_tail_threshold=threshold, stats=got,
+                **opts)
+            assert int(jnp.sum(carry[0] != n)) == distinct
+            P_got, _ = elim_ops.fold_edges_adaptive_pos(
+                P_got, carry[0], carry[1], n, pos_host=pos_host)
+        else:
+            P_got = fold(got)
+    want: dict = {}
+    with monkeypatch.context() as m:
+        m.setattr(elim_ops, "fold_segment_pos_collapse",
+                  _uncollapsed_warm_segment)
+        P_want = fold(want)
+    np.testing.assert_array_equal(np.asarray(P_got), np.asarray(P_want))
+    assert want["host_tails"] == 1
+    assert got.get("host_tails", 0) == (0 if case == "carry" else 1)
+    assert got["warm_duplicates"] == \
+        (0 if case == "live_under" else live - distinct)
+    if case in ("distinct_under", "carry"):
+        assert got.get("full_segments", 0) == 0
+        assert want["full_segments"] > 0
+    elif case == "compact":
+        assert got["full_segments"] > 0
+        assert compactions[0] == (c, elim_ops.pow2_at_least(2 * distinct))
+    else:
+        assert got.get("full_segments", 0) == want.get("full_segments", 0)
+    if case == "carry":
+        assert got["carried_tails"] == 1
+        assert compactions[0] == (c, c)
+    if case == "distinct_over":
+        assert got["full_segments"] > 0
+
+
 def test_fold_stats_wall_attribution():
     """Every segment kind executed must leave its t_* wall key in
     stats, each key non-negative and summing to (well under) the call's
