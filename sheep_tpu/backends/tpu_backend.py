@@ -377,9 +377,6 @@ class TpuBackend(Partitioner):
                  alpha: float = 1.0, segment_rounds: int = 2,
                  warm_schedule=None, cache_chunks: bool = True,
                  host_tail_threshold: int = -1,
-                 carry_tail: Optional[bool] = None,
-                 tail_overlap: Optional[bool] = None,
-                 stale_reuse: int = 1,
                  dispatch_batch: int = 1,
                  inflight: int = 1,
                  donate_buffers: Optional[bool] = None,
@@ -404,32 +401,6 @@ class TpuBackend(Partitioner):
         # expensive relative to the native host pass), auto (C/8, min
         # 2^16) on cpu-jax where the measured sweet spot is later handoff
         self.host_tail_threshold = host_tail_threshold
-        # carry the fixpoint tail of intermediate chunks into the next
-        # chunk's fold instead of host-finishing each one — saves the
-        # per-chunk O(V) table round-trip and the serialized native
-        # tail pass; one host tail remains, after the last chunk.
-        # Default OFF (None -> False): measured at RMAT-20x16 on
-        # cpu-jax, carrying makes the DEVICE grind the displacement
-        # cascades the native pass resolves in O(chain) — device rounds
-        # 18 -> 30, build 44s -> 178s, identical output (BASELINE.md
-        # "carry-over tails"). Kept as an option because the trade
-        # reverses only when the per-chunk O(V) round-trip is extremely
-        # expensive (a slow host link) — sweep --carry-tail on-chip
-        # before ever defaulting it on.
-        self.carry_tail = carry_tail
-        # overlap each chunk's host tail with the NEXT chunk's device
-        # rounds: the tail is resolved by the native pass in a worker
-        # thread and re-enters a later fold as O(changed) delta
-        # constraints (ops/elim.py host_tail_delta) instead of an O(V)
-        # table push — the device never waits for the host. Same unique
-        # forest (constraint-multiset argument; pinned by
-        # tests/test_tail_overlap.py). Default OFF pending the on-chip
-        # sweep; mutually exclusive with carry_tail.
-        self.tail_overlap = tail_overlap
-        # full segments per lifting-stack rebuild (1 = per-segment
-        # hoisting; K > 1 reuses the stack across K segments — see
-        # elim.py fold_segment_pos_stale; A/B axis in tune_fixpoint)
-        self.stale_reuse = stale_reuse
         # batched segment dispatch (ops/elim.py fold_segments_batch):
         # stage N streamed chunks as one padded [N, C] oriented block
         # and fold them in single bounded device programs — one packed
@@ -470,17 +441,6 @@ class TpuBackend(Partitioner):
         if h2d_ring < 0:
             raise ValueError("h2d_ring must be >= 0 (0 = auto)")
         self.h2d_ring = h2d_ring
-        if dispatch_batch > 1 and (carry_tail or tail_overlap):
-            raise ValueError("dispatch_batch > 1 folds whole segments on "
-                             "device; it excludes the per-chunk tail "
-                             "strategies (carry_tail / tail_overlap)")
-        if inflight > 1 and (carry_tail or tail_overlap):
-            raise ValueError("inflight > 1 pipelines whole batched "
-                             "executions; it excludes the per-chunk tail "
-                             "strategies (carry_tail / tail_overlap)")
-        if carry_tail and tail_overlap:
-            raise ValueError("carry_tail and tail_overlap are mutually "
-                             "exclusive tail strategies")
 
     def _fold_delta(self, state, edges) -> None:
         """Incremental fold (ISSUE 15): stage the delta batch as
@@ -561,11 +521,11 @@ class TpuBackend(Partitioner):
         m_cheap = stream.num_edges_cheap
         obs.progress(backend=self.name, k=int(k), edges_total=m_cheap,
                      chunks_total=-(-m_cheap // cs) if m_cheap else None)
-        carry_mode = bool(self.carry_tail)
+        # the fingerprint names the build state's format, so a
+        # checkpoint of any other format is refused on resume
         meta = ckpt.stream_meta(stream, k, cs, weights=weights,
                                 alpha=self.alpha, comm_volume=comm_volume,
-                                state_format="minp_carry" if carry_mode
-                                else "minp")
+                                state_format="minp")
         state = ckpt.resume_state(checkpointer, meta, resume)
         from_phase = ckpt.phase_index(state.phase) if state else 0
 
@@ -674,9 +634,6 @@ class TpuBackend(Partitioner):
             tail_at = self.host_tail_threshold
             if tail_at < 0:
                 tail_at = cs // 2 if jax.default_backend() != "cpu" else 0
-            from contextlib import nullcontext
-
-            from sheep_tpu.core import native as native_mod
 
             # ---- fault-tolerant build (ISSUE 9 tentpole) --------------
             # The whole streaming build runs as one retryable ATTEMPT
@@ -695,17 +652,10 @@ class TpuBackend(Partitioner):
             # The carried forest lives in POSITION space on device (P);
             # snapshots/checkpoints keep the stable vertex-space minp
             # encoding, so conversions happen only at those boundaries.
-            # In carry mode the in-flight actives are part of the state
-            # and snapshot alongside (position space — pos is a pure
-            # function of the fingerprinted stream, stable across
-            # resume).
-            snap = {"idx": 0, "minp": None, "carry": None}
+            snap = {"idx": 0, "minp": None}
             if state and state.phase == "build":
                 snap["idx"] = state.chunk_idx
                 snap["minp"] = state.arrays["minp"]
-                if carry_mode and "carry_lo" in state.arrays:
-                    snap["carry"] = (state.arrays["carry_lo"],
-                                     state.arrays["carry_hi"])
             cfg = {"batch": batch_n, "inflight": inflight_n,
                    "donate": donate, "ring": ring_n}
 
@@ -717,241 +667,151 @@ class TpuBackend(Partitioner):
                     P = jnp.asarray(snap["minp"])[order]
                 else:
                     P = jnp.full(n + 1, n, dtype=jnp.int32)
-                carry = None
-                if carry_mode and snap["carry"] is not None:
-                    carry = (jnp.asarray(snap["carry"][0]),
-                             jnp.asarray(snap["carry"][1]))
                 batch_n = cfg["batch"]
                 inflight_n = cfg["inflight"]
                 ring_n = cfg["ring"]
-                donate = cfg["donate"] and (batch_n > 1 or inflight_n > 1)
-                overlap = (bool(self.tail_overlap) and not carry_mode
-                           and native_mod.available())
-                ov_ctx = elim_ops.TailOverlap(n, pos_host_cache) \
-                    if overlap else nullcontext()
+                if batch_n > 1 or inflight_n > 1:
+                    # batched segment dispatch, pipelined (ops/
+                    # elim.py fold_segments_pipelined): stage batch_n
+                    # chunks as one oriented [N, C] block, fold groups
+                    # in bounded multi-segment device programs with up
+                    # to inflight_n executions in flight, and pull one
+                    # packed stats word per execution ONE-BEHIND — the
+                    # host's read/orient/pad overlaps the device
+                    # fixpoint instead of alternating with it, and
+                    # donation reuses the table/staging buffers across
+                    # the chain. Warm schedule / compaction / host tail
+                    # are per-segment host decisions and do not apply
+                    # here; the forest is the same unique fixpoint
+                    # either way.
+                    build_stats["dispatch_batch"] = batch_n
+                    build_stats["inflight_depth"] = inflight_n
+                    groups = _device_chunk_groups(stream, cs, n, cache,
+                                                  start, batch_n, ring_n,
+                                                  build_stats)
 
-                with ov_ctx as ov:
+                    def staged_groups():
+                        sentinel_chunk = None
+                        for group in groups:
+                            gl = len(group)
+                            if gl < batch_n:
+                                if sentinel_chunk is None:
+                                    sentinel_chunk = jnp.full(
+                                        (cs, 2), n, jnp.int32)
+                                group = group + [sentinel_chunk] * \
+                                    (batch_n - gl)
+                            loB, hiB = elim_ops.orient_chunks_batch_pos(
+                                jnp.stack(group), pos, n)
+                            yield loB, hiB, gl
 
-                    def _flush_deltas() -> None:
-                        # resolve everything still in flight into P,
-                        # synchronously (checkpoint boundaries and the
-                        # end of the stream: saved state must be the
-                        # complete constraint multiset)
-                        nonlocal P, total_rounds
-                        ov.drain(True)
-                        inj = ov.take_inject()
-                        if inj is not None:
-                            P, r = elim_ops.fold_edges_adaptive_pos(
-                                P, inj[0], inj[1], n,
-                                lift_levels=self.lift_levels,
-                                segment_rounds=self.segment_rounds,
-                                host_tail_threshold=tail_at,
-                                stale_reuse=self.stale_reuse,
-                                pos_host=pos_host_cache,
-                                stats=build_stats)
-                            total_rounds += int(r)
+                    # rolling dispatch spans tile the pipelined build:
+                    # each one covers confirm-to-confirm (the counter
+                    # deltas carry the overlap story — host_blocked_ms
+                    # / device_gap_ms); issue/confirm interleave across
+                    # groups, so per-group spans would no longer nest
+                    dsp = obs.begin("dispatch", i=idx)
 
-                    if (batch_n > 1 or inflight_n > 1) and not carry_mode \
-                            and not overlap:
-                        # batched segment dispatch, pipelined (ops/
-                        # elim.py fold_segments_pipelined): stage
-                        # batch_n chunks as one oriented [N, C] block,
-                        # fold groups in bounded multi-segment device
-                        # programs with up to inflight_n executions in
-                        # flight, and pull one packed stats word per
-                        # execution ONE-BEHIND — the host's read/orient/
-                        # pad overlaps the device fixpoint instead of
-                        # alternating with it, and donation reuses the
-                        # table/staging buffers across the chain. Warm
-                        # schedule / compaction / host tail are
-                        # per-segment host decisions and do not apply
-                        # here; the forest is the same unique fixpoint
-                        # either way.
-                        build_stats["dispatch_batch"] = batch_n
-                        build_stats["inflight_depth"] = inflight_n
-                        groups = _device_chunk_groups(stream, cs, n,
-                                                      cache, start,
-                                                      batch_n, ring_n,
-                                                      build_stats)
-
-                        def staged_groups():
-                            sentinel_chunk = None
-                            for group in groups:
-                                gl = len(group)
-                                if gl < batch_n:
-                                    if sentinel_chunk is None:
-                                        sentinel_chunk = jnp.full(
-                                            (cs, 2), n, jnp.int32)
-                                    group = group + [sentinel_chunk] * \
-                                        (batch_n - gl)
-                                loB, hiB = \
-                                    elim_ops.orient_chunks_batch_pos(
-                                        jnp.stack(group), pos, n)
-                                yield loB, hiB, gl
-
-                        # rolling dispatch spans tile the pipelined
-                        # build: each one covers confirm-to-confirm (the
-                        # counter deltas carry the overlap story —
-                        # host_blocked_ms / device_gap_ms); issue/
-                        # confirm interleave across groups, so per-group
-                        # spans would no longer nest
-                        dsp = obs.begin("dispatch", i=idx)
-
-                        def confirmed(gl, rounds, tipP):
-                            # returns True to request a flush barrier
-                            # when a checkpoint is due: mid-pipeline the
-                            # tip table can UNDER-represent a confirmed
-                            # group whose budget-exhausted leftovers are
-                            # still queued, so the save itself happens
-                            # in flushed(), after the driver drains
-                            # everything issued
-                            nonlocal idx, dsp
-                            stats_acc.absorb(build_stats)
-                            dsp.end(rounds=int(rounds))
-                            due = False
-                            if gl is not None:
-                                prev = idx
-                                idx += gl
-                                obs.chunk_progress(idx, cs, m_cheap)
-                                for i in range(prev + 1, idx + 1):
-                                    maybe_fail("build", i - start,
-                                               kinds=("kill", "oom",
-                                                      "device"))
-                                due = checkpointer is not None and \
-                                    checkpointer.due_span(prev - start,
-                                                          idx - start)
-                            dsp = obs.begin("dispatch", i=idx)
-                            return due
-
-                        def flushed(tipP):
-                            # pipeline fully drained: idx (advanced
-                            # through every group confirmed during the
-                            # drain) and the table now agree exactly —
-                            # the sound cut for both the durable
-                            # checkpoint and the in-memory retry
-                            # snapshot
-                            arrays = {
-                                "deg": deg_host,
-                                "minp": obs.pull("flush-checkpoint",
-                                                 tipP[pos])}
-                            snap["idx"] = idx
-                            snap["minp"] = arrays["minp"]
-                            if checkpointer is not None:
-                                checkpointer.save("build", idx, arrays,
-                                                  meta)
-                            # the flushed table IS the confirmed state
-                            # (durable or in-memory snapshot): chunks
-                            # behind it are eviction-safe either way
-                            _ckpt_boundary(idx)
-
-                        staged = staged_groups()
-                        try:
-                            P, rounds = elim_ops.fold_segments_pipelined(
-                                P, staged, n,
-                                inflight=inflight_n,
-                                lift_levels=self.lift_levels,
-                                segment_rounds=self.segment_rounds,
-                                donate=donate,
-                                stats=build_stats,
-                                on_confirm=confirmed,
-                                on_flush=flushed)
-                            total_rounds += int(rounds)
-                        finally:
-                            # the discard/backstop/fault paths abandon
-                            # the staged stream mid-iteration: close
-                            # BOTH generators — a for-loop does not
-                            # close the iterator it consumes, so
-                            # staged.close() alone would leave
-                            # _device_chunk_groups (and the prefetch
-                            # worker its finally cancels) open until GC
-                            staged.close()
-                            groups.close()
-                            dsp.end()
+                    def confirmed(gl, rounds, tipP):
+                        # returns True to request a flush barrier when
+                        # a checkpoint is due: mid-pipeline the tip
+                        # table can UNDER-represent a confirmed group
+                        # whose budget-exhausted leftovers are still
+                        # queued, so the save itself happens in
+                        # flushed(), after the driver drains everything
+                        # issued
+                        nonlocal idx, dsp
                         stats_acc.absorb(build_stats)
-                    else:
-                        for padded in _device_chunks(stream, cs, n,
-                                                     cache, start,
-                                                     ring_n, build_stats):
-                            seg_sp = obs.begin("segment", i=idx)
-                            try:
-                                if overlap:
-                                    # pick up any host-resolved tails
-                                    # without waiting; they enter this
-                                    # fold as ordinary actives
-                                    ov.drain(False)
-                                    carry = ov.take_inject()
-                                step = \
-                                    elim_ops.build_chunk_step_adaptive_pos(
-                                        P, padded, pos, pos_host_cache,
-                                        n,
-                                        lift_levels=self.lift_levels,
-                                        segment_rounds=self
-                                        .segment_rounds,
-                                        warm_schedule=self.warm_schedule,
-                                        stats=build_stats,
-                                        host_tail_threshold=tail_at,
-                                        stale_reuse=self.stale_reuse,
-                                        carry=carry,
-                                        carry_out=carry_mode or overlap)
-                                if carry_mode:
-                                    P, rounds, carry = step
-                                elif overlap:
-                                    P, rounds, tail = step
-                                    carry = None
-                                    if int(tail[0].shape[0]):
-                                        build_stats["overlap_tails"] = \
-                                            build_stats.get(
-                                                "overlap_tails", 0) + 1
-                                        ov.submit(P, tail[0], tail[1])
-                                else:
-                                    P, rounds = step
-                                total_rounds += int(rounds)
-                                stats_acc.absorb(build_stats)
-                                seg_sp.end(rounds=int(rounds))
-                            finally:
-                                # idempotent: balances the span when a
-                                # fault unwinds mid-chunk so a RECOVERED
-                                # run still renders a complete tree
-                                seg_sp.end()
-                            idx += 1
+                        dsp.end(rounds=int(rounds))
+                        due = False
+                        if gl is not None:
+                            prev = idx
+                            idx += gl
                             obs.chunk_progress(idx, cs, m_cheap)
-                            maybe_fail("build", idx - start,
-                                       kinds=("kill", "oom", "device"))
-                            if checkpointer is not None and \
-                                    checkpointer.due(idx - start):
-                                if overlap:
-                                    _flush_deltas()
-                                arrays = {"deg": deg_host,
-                                          "minp": obs.pull("checkpoint",
-                                                           P[pos])}
-                                if carry_mode:
-                                    arrays["carry_lo"] = obs.pull(
-                                        "checkpoint", carry[0])
-                                    arrays["carry_hi"] = obs.pull(
-                                        "checkpoint", carry[1])
-                                snap["idx"] = idx
-                                snap["minp"] = arrays["minp"]
-                                if carry_mode:
-                                    snap["carry"] = (arrays["carry_lo"],
-                                                     arrays["carry_hi"])
-                                checkpointer.save("build", idx, arrays,
-                                                  meta)
-                                _ckpt_boundary(idx)
-                    if overlap:
-                        _flush_deltas()
-                if carry_mode and carry is not None \
-                        and int(carry[0].shape[0]):
-                    # resolve the final carried tail (the stream's ONE
-                    # host tail); plain entry point = host-finish
-                    # semantics
-                    P, rounds = elim_ops.fold_edges_adaptive_pos(
-                        P, carry[0], carry[1], n,
-                        lift_levels=self.lift_levels,
-                        segment_rounds=self.segment_rounds,
-                        host_tail_threshold=tail_at,
-                        stale_reuse=self.stale_reuse,
-                        pos_host=pos_host_cache, stats=build_stats)
-                    total_rounds += int(rounds)
+                            for i in range(prev + 1, idx + 1):
+                                maybe_fail("build", i - start,
+                                           kinds=("kill", "oom",
+                                                  "device"))
+                            due = checkpointer is not None and \
+                                checkpointer.due_span(prev - start,
+                                                      idx - start)
+                        dsp = obs.begin("dispatch", i=idx)
+                        return due
+
+                    def flushed(tipP):
+                        # pipeline fully drained: idx (advanced through
+                        # every group confirmed during the drain) and
+                        # the table now agree exactly — the sound cut
+                        # for both the durable checkpoint and the
+                        # in-memory retry snapshot
+                        arrays = {
+                            "deg": deg_host,
+                            "minp": obs.pull("flush-checkpoint",
+                                             tipP[pos])}
+                        snap["idx"] = idx
+                        snap["minp"] = arrays["minp"]
+                        if checkpointer is not None:
+                            checkpointer.save("build", idx, arrays, meta)
+                        # the flushed table IS the confirmed state
+                        # (durable or in-memory snapshot): chunks behind
+                        # it are eviction-safe either way
+                        _ckpt_boundary(idx)
+
+                    staged = staged_groups()
+                    try:
+                        P, rounds = elim_ops.fold_segments_pipelined(
+                            P, staged, n,
+                            inflight=inflight_n,
+                            lift_levels=self.lift_levels,
+                            segment_rounds=self.segment_rounds,
+                            donate=cfg["donate"],
+                            stats=build_stats,
+                            on_confirm=confirmed,
+                            on_flush=flushed)
+                        total_rounds += int(rounds)
+                    finally:
+                        # the discard/backstop/fault paths abandon the
+                        # staged stream mid-iteration: close BOTH
+                        # generators — a for-loop does not close the
+                        # iterator it consumes, so staged.close() alone
+                        # would leave _device_chunk_groups (and the
+                        # prefetch worker its finally cancels) open
+                        # until GC
+                        staged.close()
+                        groups.close()
+                        dsp.end()
+                    stats_acc.absorb(build_stats)
+                    return P
+                for padded in _device_chunks(stream, cs, n, cache, start,
+                                             ring_n, build_stats):
+                    seg_sp = obs.begin("segment", i=idx)
+                    try:
+                        P, rounds = elim_ops.build_chunk_step_adaptive_pos(
+                            P, padded, pos, pos_host_cache, n,
+                            lift_levels=self.lift_levels,
+                            segment_rounds=self.segment_rounds,
+                            warm_schedule=self.warm_schedule,
+                            stats=build_stats,
+                            host_tail_threshold=tail_at)
+                        total_rounds += int(rounds)
+                        stats_acc.absorb(build_stats)
+                        seg_sp.end(rounds=int(rounds))
+                    finally:
+                        # idempotent: balances the span when a fault
+                        # unwinds mid-chunk so a RECOVERED run still
+                        # renders a complete tree
+                        seg_sp.end()
+                    idx += 1
+                    obs.chunk_progress(idx, cs, m_cheap)
+                    maybe_fail("build", idx - start,
+                               kinds=("kill", "oom", "device"))
+                    if checkpointer is not None and \
+                            checkpointer.due(idx - start):
+                        arrays = {"deg": deg_host,
+                                  "minp": obs.pull("checkpoint", P[pos])}
+                        snap["idx"] = idx
+                        snap["minp"] = arrays["minp"]
+                        checkpointer.save("build", idx, arrays, meta)
+                        _ckpt_boundary(idx)
                 return P
 
             from sheep_tpu.utils import retry as retry_mod
@@ -987,9 +847,6 @@ class TpuBackend(Partitioner):
             def _save_snapshot():
                 if checkpointer is not None and snap["minp"] is not None:
                     arrays = {"deg": deg_host, "minp": snap["minp"]}
-                    if carry_mode and snap["carry"] is not None:
-                        arrays["carry_lo"] = snap["carry"][0]
-                        arrays["carry_hi"] = snap["carry"][1]
                     checkpointer.save("build", snap["idx"], arrays, meta)
 
             def _on_device_loss():

@@ -127,30 +127,6 @@ def build_parser() -> argparse.ArgumentParser:
     p.add_argument("--no-cache-chunks", action="store_true",
                    help="disable the device-resident edge-chunk cache "
                         "(tpu backend re-streams each pass)")
-    p.add_argument("--carry-tail", dest="carry_tail", action="store_true",
-                   default=None,
-                   help="carry intermediate chunks' fixpoint tails into "
-                        "the next chunk's fold instead of host-finishing "
-                        "each one (tpu backend; default off — measured "
-                        "slower except on extreme-latency device links, "
-                        "see BASELINE.md)")
-    p.add_argument("--no-carry-tail", dest="carry_tail",
-                   action="store_false",
-                   help="host-finish every chunk's tail (see --carry-tail)")
-    p.add_argument("--tail-overlap", dest="tail_overlap",
-                   action="store_true", default=None,
-                   help="resolve each chunk's fixpoint tail on host in a "
-                        "worker thread while the device folds the next "
-                        "chunk; resolved links re-enter a later fold as "
-                        "O(changed) delta constraints (tpu backend; same "
-                        "forest bit-for-bit; excludes --carry-tail)")
-    p.add_argument("--no-tail-overlap", dest="tail_overlap",
-                   action="store_false",
-                   help="serialize host tails (see --tail-overlap)")
-    p.add_argument("--stale-reuse", type=int, default=None,
-                   help="tpu backend: full segments per lifting-stack "
-                        "rebuild (1 = per-segment hoisting; K > 1 reuses "
-                        "one stale stack across K segments)")
     p.add_argument("--dispatch-batch", type=int, default=None, metavar="N",
                    help="tpu/tpu-sharded: stage N streamed chunks as one "
                         "padded [N, C] block and fold them in single "
@@ -158,8 +134,7 @@ def build_parser() -> argparse.ArgumentParser:
                         "per execution instead of per fixpoint segment "
                         "(default 1 = the adaptive per-segment driver; "
                         "2 is refused on a TPU; the forest is "
-                        "bit-identical either way). Excludes "
-                        "--carry-tail/--tail-overlap")
+                        "bit-identical either way)")
     p.add_argument("--inflight", type=int, default=None, metavar="D",
                    help="tpu/tpu-sharded: depth of the asynchronous "
                         "dispatch pipeline — keep up to D batched device "
@@ -168,7 +143,7 @@ def build_parser() -> argparse.ArgumentParser:
                         "transfer and the device fixpoint overlap "
                         "instead of alternating (default 1 = synchronous "
                         "dispatch; the forest is bit-identical at every "
-                        "depth). Excludes --carry-tail/--tail-overlap")
+                        "depth)")
     p.add_argument("--h2d-ring", type=int, default=None, metavar="D",
                    help="tpu backend: staged host->device ring depth — "
                         "keep up to D pre-padded chunk blocks' "
@@ -469,9 +444,6 @@ def _run(parser, args) -> int:
             ("--warm-schedule", args.warm_schedule),
             ("--host-tail-threshold", args.host_tail_threshold),
             ("--no-cache-chunks", args.no_cache_chunks or None),
-            ("--carry-tail", args.carry_tail),
-            ("--tail-overlap", args.tail_overlap),
-            ("--stale-reuse", args.stale_reuse),
             ("--dispatch-batch", args.dispatch_batch),
             ("--inflight", args.inflight),
             ("--h2d-ring", args.h2d_ring),
@@ -621,9 +593,6 @@ def _run(parser, args) -> int:
                                  f"exist")
     if args.resume and not args.checkpoint_dir:
         build_parser().error("--resume requires --checkpoint-dir")
-    if args.carry_tail and args.tail_overlap:
-        build_parser().error("--carry-tail and --tail-overlap are mutually "
-                             "exclusive tail strategies")
     if args.auto_recipe and len(ks) > 1:
         build_parser().error("--auto-recipe takes a single --k (the "
                              "recipe is per target k)")
@@ -640,9 +609,6 @@ def _run(parser, args) -> int:
             ("--warm-schedule", args.warm_schedule),
             ("--host-tail-threshold", args.host_tail_threshold),
             ("--no-cache-chunks", args.no_cache_chunks or None),
-            ("--carry-tail", args.carry_tail),
-            ("--tail-overlap", args.tail_overlap),
-            ("--stale-reuse", args.stale_reuse),
             ("--dispatch-batch", args.dispatch_batch),
             ("--inflight", args.inflight),
             ("--h2d-ring", args.h2d_ring),
@@ -816,31 +782,13 @@ def _run(parser, args) -> int:
             ctor["host_tail_threshold"] = args.host_tail_threshold
         if args.no_cache_chunks:
             ctor["cache_chunks"] = False
-        if args.carry_tail is not None:
-            ctor["carry_tail"] = args.carry_tail
-        if args.tail_overlap is not None:
-            ctor["tail_overlap"] = args.tail_overlap
-        if args.stale_reuse is not None:
-            if args.stale_reuse < 1:
-                parser.error("--stale-reuse must be >= 1")
-            ctor["stale_reuse"] = args.stale_reuse
         if args.dispatch_batch is not None:
             if args.dispatch_batch < 1:
                 parser.error("--dispatch-batch must be >= 1")
-            if args.dispatch_batch > 1 and (args.carry_tail or
-                                            args.tail_overlap):
-                parser.error("--dispatch-batch > 1 folds whole segments "
-                             "on device; it excludes --carry-tail/"
-                             "--tail-overlap")
             ctor["dispatch_batch"] = args.dispatch_batch
         if args.inflight is not None:
             if args.inflight < 1:
                 parser.error("--inflight must be >= 1")
-            if args.inflight > 1 and (args.carry_tail or
-                                      args.tail_overlap):
-                parser.error("--inflight > 1 pipelines whole batched "
-                             "executions; it excludes --carry-tail/"
-                             "--tail-overlap")
             ctor["inflight"] = args.inflight
         if args.h2d_ring is not None:
             if args.h2d_ring < 0:

@@ -44,8 +44,8 @@ COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
 _ANNOTATION = None  # jax.profiler.TraceAnnotation, once JAX is imported
 _SCOPE: ContextVar = ContextVar("sheep_obs_stats", default=None)
-# scopes are carried to worker threads (prefetch, tail overlap), so
-# the read-modify-write of one counter may race across threads
+# scopes are carried to the prefetch worker thread, so the
+# read-modify-write of one counter may race across threads
 _LOCK = threading.Lock()
 _LISTENING = False
 

@@ -229,7 +229,8 @@ def _pos_round_body_stale(n: int, tables: tuple):
     """Round body for :func:`fold_segment_pos_hoisted`: identical
     retire/displace semantics to :func:`_pos_round_body` (exact
     descent), but the lifting tables above level 0 are STALE closures —
-    built once per segment — while level 0 is always the CURRENT table.
+    built once per segment — while level 0 is always the CURRENT table
+    (one-step progress per live slot, so no livelock).
     Sound because ancestor-ship is permanent (when a parent improves,
     the displaced constraint re-links the old parent above the new
     one), so a stale table's jumps land on genuine — just possibly
@@ -238,7 +239,7 @@ def _pos_round_body_stale(n: int, tables: tuple):
     segment, the round's dominant V-term (BASELINE.md 'stale lifting
     tables').
 
-    ``tables`` holds (table, live) pairs from :func:`build_lift_tables`.
+    ``tables`` holds (table, live) pairs from :func:`_lift_tables`.
     A level whose table is all sentinel would gather ``n`` for every
     slot, and ``n < hiP`` never holds, so its jump is skipped under a
     ``lax.cond`` on ``live``: the same ``cur`` for none of the C-wide
@@ -276,48 +277,14 @@ def _pos_round_body_stale(n: int, tables: tuple):
     return body
 
 
-@partial(jax.jit, static_argnames=("n", "lift_levels", "segment_rounds"))
-def fold_segment_pos_hoisted(
-    P: jax.Array,
-    loP: jax.Array,
-    hiP: jax.Array,
-    n: int,
-    lift_levels: int = 0,
-    segment_rounds: int = 32,
-):
-    """:func:`fold_segment_pos` (exact descent) with the lifting-table
-    stack HOISTED out of the round loop: tables t_1..t_{L-1} are built
-    once from the entry table and stay fixed for the whole segment;
-    only level 0 (the table itself) is current inside rounds. Same
-    (loP, hiP, P, stats) contract, with a fourth stats entry: the
-    number of stale levels that are live (:func:`fold_segment_pos_stale`).
-    The final forest is the same unique
-    fixpoint (stale jumps are sound, see :func:`_pos_round_body_stale`);
-    per-round trajectories may differ from the fresh-table body, so the
-    adaptive driver treats round counts as diagnostics, not contracts.
-
-    Fixpoint-exit soundness: the driver loop only stops on a segment
-    reporting no change, and every segment starts with tables freshly
-    built from its entry table — a first round that changes nothing ran
-    with a fully-current view, so 'no change' is a genuine fixpoint."""
-    return fold_segment_pos_stale(P, loP, hiP,
-                                  build_lift_tables(P, n, lift_levels),
-                                  n, segment_rounds=segment_rounds)
-
-
-@partial(jax.jit, static_argnames=("n", "lift_levels"))
-def build_lift_tables(P: jax.Array, n: int, lift_levels: int = 0):
-    """The exact-descent lifting stack t_1..t_{L-1} as a standalone
-    program, for CROSS-SEGMENT reuse (``stale_reuse`` > 1 in the
-    adaptive driver): (L-1) x V squaring gathers once per rebuild
-    instead of once per segment.
-
-    Returns (table, live) pairs, ``live`` a device bool: the table
-    holds some ancestor other than the sentinel ``n``. The forest is
-    usually shallower than 2^(L-1) (at V = 2^20 a Graph500 forest's
-    tables are all sentinel after 9-17 squarings of 20), and an
-    all-sentinel table squares to itself, so past the first dead level
-    each squaring is a ``lax.cond`` pass-through, not a V-wide gather."""
+def _lift_tables(P: jax.Array, n: int, lift_levels: int):
+    """The exact-descent lifting stack t_1..t_{L-1} built from ``P``,
+    as (table, live) pairs, ``live`` a device bool: the table holds
+    some ancestor other than the sentinel ``n``. The forest is usually
+    shallower than 2^(L-1) (at V = 2^20 a Graph500 forest's tables are
+    all sentinel after 9-17 squarings of 20), and an all-sentinel table
+    squares to itself, so past the first dead level each squaring is a
+    ``lax.cond`` pass-through, not a V-wide gather."""
     lift_levels, _ = _resolve(n, lift_levels, "exact")
     t = P.astype(jnp.int32)
     live = jnp.any(t != n)
@@ -329,31 +296,35 @@ def build_lift_tables(P: jax.Array, n: int, lift_levels: int = 0):
     return tuple(tables)
 
 
-@partial(jax.jit, static_argnames=("n", "segment_rounds"))
-def fold_segment_pos_stale(
+@partial(jax.jit, static_argnames=("n", "lift_levels", "segment_rounds"))
+def fold_segment_pos_hoisted(
     P: jax.Array,
     loP: jax.Array,
     hiP: jax.Array,
-    tables: tuple,
     n: int,
+    lift_levels: int = 0,
     segment_rounds: int = 32,
 ):
-    """:func:`fold_segment_pos_hoisted` with the stack passed IN
-    (:func:`build_lift_tables`) so the driver can reuse it across
-    several segments. Soundness is the stronger form the stale round
-    body already satisfies: level 0 is always current (one-step
-    progress per live slot, so no livelock — a constraint whose level-0
-    jump is inadmissible retires by scatter-min within two rounds), and
-    a no-change segment is a genuine fixpoint REGARDLESS of stack
-    freshness, because slots only change toward progress and the table
-    only changes through a retiring slot (see _pos_round_body). Stale
-    jumps land on genuine ancestors (permanence), so the unique
-    fixpoint is unchanged; only round counts differ.
+    """:func:`fold_segment_pos` (exact descent) with the lifting-table
+    stack HOISTED out of the round loop: tables t_1..t_{L-1} are built
+    once from the entry table (:func:`_lift_tables`) and stay fixed for
+    the whole segment; only level 0 (the table itself) is current
+    inside rounds. The final forest is the same unique fixpoint (stale
+    jumps are sound, see :func:`_pos_round_body_stale`); per-round
+    trajectories may differ from the fresh-table body, so the adaptive
+    driver treats round counts as diagnostics, not contracts.
+
+    Fixpoint-exit soundness: the driver loop only stops on a segment
+    reporting no change, and a no-change segment is a genuine fixpoint
+    whatever the stack's freshness, because slots only change toward
+    progress and the table only changes through a retiring slot (see
+    :func:`_pos_round_body`).
 
     ``stats`` is int32[4]: :func:`_run_segment`'s (changed, rounds,
     live) and the number of live stale levels, pulled in the same
     packed read."""
-    body = _pos_round_body_stale(n, tuple(tables))
+    tables = _lift_tables(P, n, lift_levels)
+    body = _pos_round_body_stale(n, tables)
     loP, hiP, P, stats = _run_segment(body, P, loP, hiP, n, segment_rounds)
     levels = sum((live.astype(jnp.int32) for _, live in tables),
                  jnp.int32(0))
@@ -1209,125 +1180,7 @@ def _host_tail_finish_pos(P, loP, hiP, n: int, size: int, pos_host):
     return jnp.asarray(newP)
 
 
-def host_tail_delta(P_snap, loP, hiP, n: int, pos_host):
-    """Resolve a compacted fixpoint tail on HOST and return it as DELTA
-    constraints instead of a replacement table.
-
-    Same native Liu pass as :func:`_host_tail_finish_pos`, but the result
-    is the set of (position, new_parent_position) pairs whose parent
-    CHANGED — exactly the tree edges the resolution added. Injecting
-    those pairs as ordinary actives into any later fold yields the same
-    unique fixpoint (the forest is a function of the inserted constraint
-    multiset; a resolved link is a derived tree edge of a sub-multiset,
-    which is what :func:`merge_forests` folds), so the caller can run
-    the native pass in a worker thread while the device folds the next
-    chunk, and ship an O(changed) delta instead of the O(V) table push.
-
-    Inputs must be HOST-safe snapshots (jax arrays are immutable, so the
-    device arrays themselves are safe); everything here is numpy + the
-    native core — no jax dispatch — making it executor-thread-friendly
-    apart from the initial pulls."""
-    from sheep_tpu.core import native
-
-    lo_np = obs.pull("tail-overlap-lo", loP)
-    hi_np = obs.pull("tail-overlap-hi", hiP)
-    mask = lo_np != n
-    pos_host = np.asarray(pos_host)
-    order_host = _order_host(pos_host, n)
-    edges = np.stack([order_host[lo_np[mask]], order_host[hi_np[mask]]],
-                     axis=1)
-    # O(V) pull overlapped with device work
-    P_np = obs.pull("tail-overlap-table", P_snap)
-    pp = P_np[pos_host]
-    parent = np.where(pp < n, order_host[np.minimum(pp, n)],
-                      NO_PARENT).astype(np.int64)
-    # native.build_elim_tree writes into a contiguous int64 parent array
-    # IN PLACE (and returns it) — diff against a snapshot, not the alias
-    new_parent = native.build_elim_tree(edges, pos_host, parent.copy())
-    ch = np.nonzero(new_parent != parent)[0]
-    # links are only ever added or improved, never removed
-    assert len(ch) == 0 or new_parent[ch].min() >= 0
-    dlo = pos_host[ch].astype(np.int32)
-    dhi = pos_host[new_parent[ch]].astype(np.int32)
-    return dlo, dhi
-
-
-def pad_actives_pow2(dlo, dhi, n: int, floor: int = 1 << 14):
-    """Pad host (dlo, dhi) constraint arrays to a power-of-two length
-    with the inert (n, n) sentinel so injected carries come from a small
-    set of static shapes (one compile per bucket, not per delta)."""
-    size = pow2_at_least(max(1, len(dlo)), floor=floor)
-    out_lo = np.full(size, n, dtype=np.int32)
-    out_hi = np.full(size, n, dtype=np.int32)
-    out_lo[: len(dlo)] = dlo
-    out_hi[: len(dhi)] = dhi
-    return jnp.asarray(out_lo), jnp.asarray(out_hi)
-
-
-class TailOverlap:
-    """Worker-thread host-tail pipeline shared by the tpu backend and the
-    tuning tool: submit compacted tails to :func:`host_tail_delta`, drain
-    finished resolutions, and hand them back as padded injection carries.
-
-    Use as a context manager so the single worker thread (and any
-    in-flight O(V) pull) is released even when the driving loop raises —
-    a leaked non-daemon thread blocks interpreter exit until its pending
-    job finishes, which on a wedged device link means a hang instead of
-    a fast failure."""
-
-    def __init__(self, n: int, pos_host):
-        from concurrent.futures import ThreadPoolExecutor
-
-        self.n = n
-        self.pos_host = pos_host
-        self._executor = ThreadPoolExecutor(max_workers=1)
-        self._pending: list = []   # in-flight futures, FIFO
-        self._deltas: list = []    # resolved (dlo, dhi) awaiting injection
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        self._executor.shutdown(wait=True)
-        return False
-
-    def submit(self, P, loP, hiP) -> None:
-        """Queue a compacted live tail (device arrays are immutable, so
-        the P snapshot is safe to pull from the worker thread). The
-        worker runs in the caller's context: its pulls and native wall
-        count into the caller's stats scope."""
-        import contextvars
-
-        self._pending.append(self._executor.submit(
-            contextvars.copy_context().run, host_tail_delta, P, loP, hiP,
-            self.n, self.pos_host))
-
-    def drain(self, block: bool) -> None:
-        while self._pending and (block or self._pending[0].done()):
-            d = self._pending.pop(0).result()
-            if len(d[0]):
-                self._deltas.append(d)
-
-    def take_inject(self):
-        """All drained deltas as one padded (loP, hiP) carry, or None."""
-        if not self._deltas:
-            return None
-        dlo = np.concatenate([d[0] for d in self._deltas])
-        dhi = np.concatenate([d[1] for d in self._deltas])
-        self._deltas.clear()
-        return pad_actives_pow2(dlo, dhi, self.n)
-
-
-def _fold_adaptive_pos_impl(*args, **kwargs):
-    """:func:`_fold_adaptive_pos_impl_body` under the SHEEP_SANITIZE
-    stray-sync guard: the adaptive driver's only designed host reads
-    are the per-segment packed sv pull and the host-tail handoff —
-    any other implicit device->host conversion in the loop raises."""
-    with sanitize.guard("adaptive-fold"):
-        return _fold_adaptive_pos_impl_body(*args, **kwargs)
-
-
-def _fold_adaptive_pos_impl_body(
+def _fold_adaptive_pos_body(
     P: jax.Array,
     loP: jax.Array,
     hiP: jax.Array,
@@ -1343,21 +1196,8 @@ def _fold_adaptive_pos_impl_body(
     warm_schedule: tuple,
     pos_host,
     stats,
-    carry_out: bool,
-    stale_tables: bool = True,
-    stale_reuse: int = 1,
 ):
-    """Shared adaptive-fixpoint loop; returns (P, total, carry) where
-    ``carry`` is None (converged / host-finished) or a compacted
-    (carry_loP, carry_hiP) of the still-live constraints (carry_out mode,
-    see :func:`fold_edges_adaptive_pos_carry`).
-
-    ``stale_reuse`` = full segments per lifting-stack rebuild (exact
-    descent with stale_tables only). 1 = the landed per-segment
-    hoisting; K > 1 reuses one stack across K segments
-    (:func:`fold_segment_pos_stale`), cutting the (L-1) x V squaring
-    gathers — the dominant V-term — by a further factor K at the price
-    of weaker (never unsound) jumps between rebuilds."""
+    """The loop of :func:`fold_edges_adaptive_pos`; returns (P, total)."""
     from sheep_tpu.core import native
 
     # the CLI validates R:L >= 1 at parse time; validate the Python API
@@ -1383,8 +1223,6 @@ def _fold_adaptive_pos_impl_body(
         # cheaper relative to the host pass, so callers may lower it
         host_tail_threshold = max(1 << 16, size // 8)
     warm = list(warm_schedule)
-    lift_stack = None
-    segs_on_stack = 0
 
     def t_add(key: str, dt: float) -> None:
         # wall-clock attribution per segment KIND. Dispatches are async,
@@ -1422,27 +1260,13 @@ def _fold_adaptive_pos_impl_body(
         elif size > small_size:
             seg = min(segment_rounds, max_rounds - total)
             rl, rd = _resolve(n, lift_levels, descent)
-            if stale_tables and rd == "exact" and seg > 1:
+            if rd == "exact" and seg > 1:
                 # exact descent with per-SEGMENT (stale) tables: saves
                 # (seg-1)/seg of the L x V squaring gathers — the
                 # round's dominant V-term (same unique fixpoint; see
                 # fold_segment_pos_hoisted)
-                if stale_reuse > 1:
-                    if lift_stack is None or segs_on_stack >= stale_reuse:
-                        # release the old stack BEFORE building the new
-                        # one: both alive at once would transiently
-                        # double the (EXACT_TABLE_BYTES-scale) footprint
-                        lift_stack = None
-                        lift_stack = build_lift_tables(P, n, rl)
-                        segs_on_stack = 0
-                        stats["stack_rebuilds"] = \
-                            stats.get("stack_rebuilds", 0) + 1
-                    loP, hiP, P, sv = fold_segment_pos_stale(
-                        P, loP, hiP, lift_stack, n, segment_rounds=seg)
-                    segs_on_stack += 1
-                else:
-                    loP, hiP, P, sv = fold_segment_pos_hoisted(
-                        P, loP, hiP, n, lift_levels=rl, segment_rounds=seg)
+                loP, hiP, P, sv = fold_segment_pos_hoisted(
+                    P, loP, hiP, n, lift_levels=rl, segment_rounds=seg)
             else:
                 loP, hiP, P, sv = fold_segment_pos(
                     P, loP, hiP, n, lift_levels=lift_levels,
@@ -1471,7 +1295,7 @@ def _fold_adaptive_pos_impl_body(
             stats["warm_duplicates"] = \
                 stats.get("warm_duplicates", 0) + int(sv[3])
         elif len(sv) > 3:
-            # a stale-table segment (L = rl levels): its fourth entry
+            # a hoisted segment (L = rl levels): its fourth entry
             # counts the lifting levels that were not all sentinel
             levels = int(sv[3])
             stats["lift_levels_live"] = \
@@ -1488,25 +1312,10 @@ def _fold_adaptive_pos_impl_body(
         stats["device_rounds"] = stats.get("device_rounds", 0) + r
         # live == 0 is the fixpoint too (the table only changes through
         # a retiring slot): return immediately rather than paying an
-        # empty host tail / an all-dead carry buffer / one extra
-        # confirming segment
+        # empty host tail or one extra confirming segment
         if not changed or live == 0 or total >= max_rounds:
-            return P, total, None
+            return P, total
         if live <= host_tail_threshold:
-            if carry_out:
-                # hand the still-live tail to the NEXT chunk's fold
-                # instead of the host: the displaced cascade keeps
-                # climbing inside the next chunk's (efficient, wide)
-                # rounds, and the per-chunk O(V) table round-trip +
-                # sequential native pass disappear. Sound because the
-                # fixpoint is a property of the inserted constraint
-                # multiset, not of when each constraint resolves.
-                stats["carried_tails"] = stats.get("carried_tails", 0) + 1
-                stats["carried_live"] = stats.get("carried_live", 0) + live
-                cap = min(pow2_at_least(live, floor=1 << 14), size)
-                with obs.profiler_span("compact"):
-                    carry = compact_actives(loP, hiP, n, cap, dedup=True)
-                return P, total, carry
             if use_host_tail:
                 stats["host_tails"] = stats.get("host_tails", 0) + 1
                 stats["host_tail_live"] = \
@@ -1519,7 +1328,7 @@ def _fold_adaptive_pos_impl_body(
                     out = _host_tail_finish_pos(P, loP, hiP, n,
                                                 min(pull, size), pos_host)
                 t_add("t_host_tail_s", time.perf_counter() - t0)
-                return out, total, None
+                return out, total
         if size > small_size and live <= size // 2:
             new_size = pow2_at_least(2 * live, floor=small_size)
             if new_size < size:
@@ -1546,8 +1355,6 @@ def fold_edges_adaptive_pos(
     warm_schedule: tuple = (),
     pos_host=None,
     stats=None,
-    stale_tables: bool = True,
-    stale_reuse: int = 1,
 ):
     """Host-driven fixpoint with active-set compaction and a host-finished
     tail — same unique forest as :func:`fold_edges`, far less work.
@@ -1569,7 +1376,10 @@ def fold_edges_adaptive_pos(
       ``host_tail_threshold`` slots are live
       (:func:`fold_segment_pos_collapse`), so the decisions below see
       the distinct count
-    - full mode: lifting-table segments on the current buffer
+    - full mode: lifting-table segments on the current buffer — exact
+      descent with more than one round a segment runs
+      :func:`fold_segment_pos_hoisted` (the stack built once a segment),
+      anything else :func:`fold_segment_pos`
     - after each segment, if live count <= size/2, compact the buffer to
       max(small_size, 2*live) rounded up to a power of two (each size is
       one extra compiled program; sizes shrink geometrically, so at most
@@ -1580,51 +1390,17 @@ def fold_edges_adaptive_pos(
       for one O(V) table round-trip per chunk
     - fallback (no native core): jump-mode rounds at ``small_size`` —
       O(C') gathers per round, independent of V
+
+    Runs under the SHEEP_SANITIZE stray-sync guard: the driver's only
+    designed host reads are the per-segment packed sv pull and the
+    host-tail handoff — any other implicit device->host conversion in
+    the loop raises.
     """
-    P, total, _ = _fold_adaptive_pos_impl(
-        P, loP, hiP, n, lift_levels, segment_rounds, descent, max_rounds,
-        small_size, small_jumps, host_tail, host_tail_threshold,
-        warm_schedule, pos_host, stats, carry_out=False,
-        stale_tables=stale_tables, stale_reuse=stale_reuse)
-    return P, total
-
-
-def fold_edges_adaptive_pos_carry(
-    P: jax.Array,
-    loP: jax.Array,
-    hiP: jax.Array,
-    n: int,
-    **opts,
-):
-    """Carry-out variant of :func:`fold_edges_adaptive_pos` for
-    intermediate stream chunks: instead of host-finishing the tail, the
-    still-live constraints are compacted and RETURNED as
-    ``(P, rounds, (carry_loP, carry_hiP))`` for the caller to prepend to
-    the next chunk's actives (empty carry when converged). Eliminates the
-    per-chunk O(V) device->host->device table round-trip and the
-    serialized native tail pass; only the stream's FINAL fold (on the
-    last carry, via the plain entry point) pays one host tail. The final
-    forest is identical — the fixpoint is determined by the inserted
-    constraint multiset, not by when each constraint resolves
-    (tests/test_tpu_ops.py pins streaming-vs-batch equality with carry
-    on)."""
-    args = (opts.pop("lift_levels", 0), opts.pop("segment_rounds", 2),
-            opts.pop("descent", "auto"), opts.pop("max_rounds", 1 << 20),
-            opts.pop("small_size", 1 << 14), opts.pop("small_jumps", 16),
-            opts.pop("host_tail", True), opts.pop("host_tail_threshold", 0),
-            opts.pop("warm_schedule", ()), opts.pop("pos_host", None),
-            opts.pop("stats", None))
-    stale = opts.pop("stale_tables", True)
-    reuse = opts.pop("stale_reuse", 1)
-    if opts:  # reject typos BEFORE the (potentially minutes-long) fold
-        raise TypeError(f"unknown options: {sorted(opts)}")
-    P, total, carry = _fold_adaptive_pos_impl(P, loP, hiP, n, *args,
-                                              carry_out=True,
-                                              stale_tables=stale,
-                                              stale_reuse=reuse)
-    if carry is None:
-        carry = (jnp.zeros(0, jnp.int32), jnp.zeros(0, jnp.int32))
-    return P, total, carry
+    with sanitize.guard("adaptive-fold"):
+        return _fold_adaptive_pos_body(
+            P, loP, hiP, n, lift_levels, segment_rounds, descent,
+            max_rounds, small_size, small_jumps, host_tail,
+            host_tail_threshold, warm_schedule, pos_host, stats)
 
 
 def fold_edges_adaptive(
@@ -1831,24 +1607,13 @@ def build_chunk_step_adaptive_pos(
     (and checkpoint) boundaries, so the steady-state loop runs zero
     vertex<->position conversions. Extra ``fold_opts`` (e.g.
     host_tail_threshold) forward to :func:`fold_edges_adaptive_pos`.
-
-    ``carry`` = (loP, hiP) actives carried over from the previous
-    chunk's fold (prepended to this chunk's oriented actives);
-    ``carry_out=True`` selects the carry-returning variant — the step
-    then returns (P, rounds, carry) instead of (P, rounds)."""
-    carry = fold_opts.pop("carry", None)
-    carry_out = fold_opts.pop("carry_out", False)
+    Returns (P, rounds)."""
     loP, hiP = orient_edges_pos(chunk, pos, n)
-    if carry is not None and int(carry[0].shape[0]):
-        loP = jnp.concatenate([loP, carry[0]])
-        hiP = jnp.concatenate([hiP, carry[1]])
-    fold = fold_edges_adaptive_pos_carry if carry_out \
-        else fold_edges_adaptive_pos
-    return fold(P, loP, hiP, n, lift_levels=lift_levels,
-                segment_rounds=segment_rounds,
-                warm_schedule=warm_schedule,
-                pos_host=pos_host, stats=stats,
-                **fold_opts)
+    return fold_edges_adaptive_pos(P, loP, hiP, n, lift_levels=lift_levels,
+                                   segment_rounds=segment_rounds,
+                                   warm_schedule=warm_schedule,
+                                   pos_host=pos_host, stats=stats,
+                                   **fold_opts)
 
 
 @partial(jax.jit, static_argnames=("n", "lift_levels"))

@@ -222,15 +222,42 @@ def test_cli_checkpoint_resume(tmp_path, monkeypatch):
 
 
 @pytest.mark.parametrize("phase", ["build", "score"])
-def test_fault_then_resume_carry_mode(tmp_path, phase, monkeypatch):
-    """Kill+resume with carry-over tails: the in-flight carried actives
-    are checkpointed state, so the resumed run must still match the
-    uninterrupted one exactly."""
+def test_fault_then_resume_under_warm_collapse(tmp_path, phase,
+                                               monkeypatch):
+    """Kill+resume where the second chunk's warm segment collapses
+    duplicates and hands the distinct constraints straight to the host
+    tail (threshold between the distinct and the live count): the
+    resumed run matches the uninterrupted one, and a resumed build
+    refolds that chunk the same way."""
     if "tpu" not in list_backends():
         pytest.skip("tpu backend unavailable")
-    es = graph()
-    kw = {"chunk_edges": CHUNK, "carry_tail": True}
+    import jax.numpy as jnp
+
+    from sheep_tpu.backends.tpu_backend import pad_chunk
+    from sheep_tpu.ops import elim as elim_ops
+
+    n, c = 1 << 12, 1 << 15
+    e = generators.rmat(12, 16, seed=1)
+    assert len(e) == 2 * c
+    es = EdgeStream.from_array(e, n_vertices=n)
+    pos_host = get_backend("tpu", chunk_edges=c).partition(
+        es, K, keep_tree=True).tree["pos"]
+    pos = jnp.asarray(np.append(pos_host, n).astype(np.int32))
+    P, _ = elim_ops.build_chunk_step_adaptive_pos(
+        jnp.full(n + 1, n, dtype=jnp.int32), pad_chunk(e[:c], c, n), pos,
+        pos_host, n)
+    loP, hiP = elim_ops.orient_edges_pos(
+        jnp.asarray(pad_chunk(e[c:], c, n)), pos, n)
+    wlo, whi, _, _ = elim_ops.fold_segment_pos(
+        P, loP, hiP, n, lift_levels=8, segment_rounds=1, descent="stream")
+    live, distinct = (int(x) for x in
+                      elim_ops.count_live_distinct(wlo, whi, n))
+    threshold = (live + distinct) // 2
+    assert distinct <= threshold < live
+
+    kw = {"chunk_edges": c, "host_tail_threshold": threshold}
     expect = get_backend("tpu", **kw).partition(es, K, comm_volume=True)
+    assert expect.diagnostics["warm_duplicates"] >= live - distinct
 
     ck = Checkpointer(str(tmp_path), every=1)
     monkeypatch.setenv(ENV_VAR, f"{phase}:2")
@@ -238,13 +265,17 @@ def test_fault_then_resume_carry_mode(tmp_path, phase, monkeypatch):
         get_backend("tpu", **kw).partition(
             es, K, comm_volume=True, checkpointer=ck)
     monkeypatch.delenv(ENV_VAR)
-    assert ck.load() is not None
+    assert ck.load().phase == phase and ck.load().chunk_idx == 1
 
     res = get_backend("tpu", **kw).partition(
         es, K, comm_volume=True, checkpointer=ck, resume=True)
     assert np.array_equal(res.assignment, expect.assignment)
     assert res.edge_cut == expect.edge_cut
     assert res.comm_volume == expect.comm_volume
+    if phase == "build":
+        assert res.diagnostics["warm_duplicates"] == live - distinct
+        assert res.diagnostics["host_tails"] == 1
+        assert res.diagnostics.get("full_segments", 0) == 0
 
 
 # ------------------------------------- graceful degradation (ISSUE 8)
@@ -546,20 +577,34 @@ def test_cli_k_levels_checkpoint_resume(tmp_path, monkeypatch):
                           formats.read_partition(out2))
 
 
-def test_carry_checkpoint_gated_from_no_carry_resume(tmp_path, monkeypatch):
-    """state_format distinguishes carry-mode checkpoints, so a checkpoint
-    written with carry_tail=True refuses a carry_tail=False resume
-    (different in-flight state shape) instead of silently dropping the
-    carried constraints."""
+def test_resume_refuses_legacy_carry_format(tmp_path, monkeypatch):
+    """A build checkpoint of the retired format that carried chunk tails
+    (state_format "minp_carry", with its in-flight actives) is refused on
+    resume rather than resumed without those constraints; the same
+    payload under the current format resumes to the uninterrupted
+    partition."""
     if "tpu" not in list_backends():
         pytest.skip("tpu backend unavailable")
     es = graph()
-    ck = Checkpointer(str(tmp_path), every=1)
+    expect = get_backend("tpu", chunk_edges=CHUNK).partition(es, K)
+    ck = Checkpointer(str(tmp_path / "minp"), every=1)
     monkeypatch.setenv(ENV_VAR, "build:2")
     with pytest.raises(InjectedFault):
-        get_backend("tpu", chunk_edges=CHUNK, carry_tail=True).partition(
+        get_backend("tpu", chunk_edges=CHUNK).partition(
             es, K, checkpointer=ck)
     monkeypatch.delenv(ENV_VAR)
+    state = ck.load()
+    assert state.phase == "build" and state.meta["state_format"] == "minp"
+
+    legacy = Checkpointer(str(tmp_path / "carry"), every=1)
+    empty = np.full(1 << 14, 1 << 10, dtype=np.int32)
+    legacy.save("build", state.chunk_idx,
+                {**state.arrays, "carry_lo": empty, "carry_hi": empty},
+                {**state.meta, "state_format": "minp_carry"})
     with pytest.raises(ValueError, match="does not match"):
-        get_backend("tpu", chunk_edges=CHUNK, carry_tail=False).partition(
-            es, K, checkpointer=ck, resume=True)
+        get_backend("tpu", chunk_edges=CHUNK).partition(
+            es, K, checkpointer=legacy, resume=True)
+
+    res = get_backend("tpu", chunk_edges=CHUNK).partition(
+        es, K, checkpointer=ck, resume=True)
+    assert np.array_equal(res.assignment, expect.assignment)

@@ -287,10 +287,10 @@ def test_degrade_ladder_skips_refused_batch(monkeypatch, platform, start,
 
 
 def test_backend_dispatch_batch_excludes_tail_strategies():
-    with pytest.raises(ValueError, match="dispatch_batch"):
-        TpuBackend(dispatch_batch=2, carry_tail=True)
-    with pytest.raises(ValueError, match="dispatch_batch"):
-        TpuBackend(dispatch_batch=-1)
+    """A batch below one is refused."""
+    for bad in (0, -1):
+        with pytest.raises(ValueError, match="dispatch_batch"):
+            TpuBackend(dispatch_batch=bad)
 
 
 def test_sharded_pipeline_dispatch_batch_matches():

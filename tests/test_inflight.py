@@ -234,12 +234,10 @@ def test_backend_inflight_without_batching():
 
 
 def test_backend_inflight_excludes_tail_strategies():
-    with pytest.raises(ValueError, match="inflight"):
-        TpuBackend(inflight=2, carry_tail=True)
-    with pytest.raises(ValueError, match="inflight"):
-        TpuBackend(inflight=2, tail_overlap=True)
-    with pytest.raises(ValueError, match="inflight"):
-        TpuBackend(inflight=-1)
+    """A depth below one is refused."""
+    for bad in (0, -1):
+        with pytest.raises(ValueError, match="inflight"):
+            TpuBackend(inflight=bad)
 
 
 def test_adaptive_driver_emits_overlap_counters():
@@ -366,5 +364,4 @@ def test_cli_inflight_validation(tmp_path):
     with pytest.raises(SystemExit):
         cli_main(["--input", str(p), "--k", "4", "--inflight", "-1"])
     with pytest.raises(SystemExit):
-        cli_main(["--input", str(p), "--k", "4", "--inflight", "2",
-                  "--carry-tail"])
+        cli_main(["--input", str(p), "--k", "4", "--inflight", "0"])

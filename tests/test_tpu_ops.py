@@ -421,11 +421,15 @@ def test_sortmerge_round_bit_identical(graph, jumps):
                                           err_msg=f"{name} diverged")
 
 
-def test_streaming_carry_matches_batch(graph):
-    """Carry-over streaming (intermediate chunks hand their live tail to
-    the NEXT chunk's fold instead of host-finishing) must converge to the
-    identical forest: the fixpoint is a property of the inserted
-    constraint multiset, not of when each constraint resolves."""
+@pytest.mark.parametrize("warm_schedule", [(), ((1, 2),)])
+def test_adaptive_pos_host_tail_every_chunk_matches_batch(graph,
+                                                          warm_schedule):
+    """The streaming step with the host tail taken straight after each
+    chunk's first segment (threshold = the chunk width) reaches the
+    whole-graph forest. A chunk takes the tail exactly when its first
+    segment left live constraints, which the same fold without a host
+    tail shows by running a second segment (a path's or a star's chunks
+    converge in their first)."""
     e, n = graph
     pos, order = _device_order(e, n)
     pos_host = np.asarray(pos[:n])
@@ -433,75 +437,78 @@ def test_streaming_carry_matches_batch(graph):
         jnp.full(n + 1, n, dtype=jnp.int32), pad_chunk(e, len(e), n),
         pos, order, n)
     P = jnp.full(n + 1, n, dtype=jnp.int32)
-    carry = None
     size = 37
-    # tiny threshold + tiny small_size force the carry branch to trigger
-    # on every chunk rather than converging within the chunk
     for off in range(0, len(e), size):
-        P, _, carry = elim_ops.build_chunk_step_adaptive_pos(
-            P, pad_chunk(e[off:off + size], size, n), pos, pos_host, n,
-            warm_schedule=((1, 2),), host_tail_threshold=size,
-            small_size=8, carry=carry, carry_out=True)
-    if int(carry[0].shape[0]):
-        P, _ = elim_ops.fold_edges_adaptive_pos(
-            P, carry[0], carry[1], n, pos_host=pos_host)
+        chunk = pad_chunk(e[off:off + size], size, n)
+        opts = dict(warm_schedule=warm_schedule, small_size=8)
+        device_only: dict = {}
+        P_dev, _ = elim_ops.build_chunk_step_adaptive_pos(
+            P, chunk, pos, pos_host, n, host_tail=False, stats=device_only,
+            **opts)
+        stats: dict = {}
+        P, _ = elim_ops.build_chunk_step_adaptive_pos(
+            P, chunk, pos, pos_host, n, host_tail_threshold=size,
+            stats=stats, **opts)
+        np.testing.assert_array_equal(np.asarray(P), np.asarray(P_dev))
+        assert stats["host_syncs"] == 1
+        assert stats.get("host_tails", 0) == \
+            (device_only["host_syncs"] > 1)
     np.testing.assert_array_equal(np.asarray(P[pos]), np.asarray(whole))
 
 
-@pytest.mark.parametrize("carry_tail", [True, False])
-def test_tpu_backend_carry_modes_match_oracle(graph, carry_tail):
-    """End-to-end backend equality in both tail modes on multi-chunk
-    streams (cpu-jax default is carry_tail=False, so True is forced)."""
+@pytest.mark.parametrize("host_tail_threshold", [-1, 1, 4, 16])
+def test_tpu_backend_matches_oracle(graph, host_tail_threshold):
+    """End-to-end backend equality on multi-chunk streams at every
+    host-tail handoff: -1 is the platform default (on cpu-jax the auto
+    threshold, so each chunk goes to the host after its first segment);
+    1, 4 and 16 hand off later, after jump-mode small segments (a
+    64-edge chunk is under the driver's small size)."""
     e, n = graph
     es = EdgeStream.from_array(e, n_vertices=n)
-    res = TpuBackend(chunk_edges=64, carry_tail=carry_tail).partition(
+    res = TpuBackend(chunk_edges=64,
+                     host_tail_threshold=host_tail_threshold).partition(
         es, 4, comm_volume=True)
     ref = pure.partition_arrays(e, 4, n=n)
     np.testing.assert_array_equal(res.assignment, ref.assignment)
     assert res.edge_cut == ref.edge_cut
     assert res.comm_volume == ref.comm_volume
+    assert res.diagnostics["small_segments"] > 0
 
 
-@pytest.mark.parametrize("stale_reuse", [2, 4])
-def test_stale_reuse_matches_oracle(graph, stale_reuse):
-    """Cross-segment stale-stack reuse (stale_reuse > 1) must reach the
-    same unique fixpoint as the fresh/per-segment paths: level 0 stays
-    current, stale jumps land on genuine ancestors, and the no-change
-    exit is a fixpoint regardless of stack freshness
-    (elim.py fold_segment_pos_stale). Multi-chunk backend run so stack
-    rebuild cadence spans chunk boundaries and host tails interleave."""
+@pytest.mark.parametrize("segment_rounds", [1, 3])
+def test_adaptive_pos_segment_rounds_match_batch(graph, segment_rounds,
+                                                 monkeypatch):
+    """Full segments on the device reach the whole-graph forest in
+    both of the driver's exact-descent programs: one round a segment
+    runs fold_segment_pos, more than one the hoisted stack."""
     e, n = graph
-    from sheep_tpu.io.edgestream import EdgeStream
-
-    es = EdgeStream.from_array(e, n_vertices=n)
-    base = TpuBackend(chunk_edges=64, segment_rounds=3).partition(es, 4)
-    reused = TpuBackend(chunk_edges=64, segment_rounds=3,
-                        stale_reuse=stale_reuse).partition(es, 4)
-    np.testing.assert_array_equal(base.assignment, reused.assignment)
-    assert base.edge_cut == reused.edge_cut
-    assert base.comm_volume == reused.comm_volume
-
-
-def test_stale_reuse_rebuild_cadence():
-    """The stack rebuild counter fires every K full segments (stats
-    diagnostic), and the forest equals the fresh-table fold."""
-    e, n = _cases()["rmat"]
     pos, order = _device_order(e, n)
-    pos_host = np.asarray(pos[:n])
-    loP, hiP = elim_ops.orient_edges_pos(
-        jnp.asarray(pad_chunk(e, len(e), n)), pos, n)
+    whole, _ = elim_ops.build_chunk_step(
+        jnp.full(n + 1, n, dtype=jnp.int32), pad_chunk(e, len(e), n),
+        pos, order, n)
+    hoisted = []
+    real = elim_ops.fold_segment_pos_hoisted
+
+    def spy(*a, **kw):
+        hoisted.append(kw["segment_rounds"])
+        return real(*a, **kw)
+
+    monkeypatch.setattr(elim_ops, "fold_segment_pos_hoisted", spy)
+    P = jnp.full(n + 1, n, dtype=jnp.int32)
     stats: dict = {}
-    P0 = jnp.full(n + 1, n, dtype=jnp.int32)
-    P_fresh, _ = elim_ops.fold_edges_adaptive_pos(
-        P0, loP, hiP, n, segment_rounds=2, small_size=8, host_tail=False,
-        stale_tables=False)
-    P_reuse, _ = elim_ops.fold_edges_adaptive_pos(
-        P0, loP, hiP, n, segment_rounds=2, small_size=8, host_tail=False,
-        stale_reuse=3, stats=stats)
-    np.testing.assert_array_equal(np.asarray(P_fresh), np.asarray(P_reuse))
-    full = stats.get("full_segments", 0)
-    assert full > 0, "config must exercise the full-segment stale path"
-    assert stats.get("stack_rebuilds", 0) == -(-full // 3)
+    size = 37
+    for off in range(0, len(e), size):
+        loP, hiP = elim_ops.orient_edges_pos(
+            jnp.asarray(pad_chunk(e[off:off + size], size, n)), pos, n)
+        P, _ = elim_ops.fold_edges_adaptive_pos(
+            P, loP, hiP, n, segment_rounds=segment_rounds, small_size=8,
+            host_tail=False, stats=stats)
+    assert stats["full_segments"] > 0
+    if segment_rounds == 1:
+        assert hoisted == []
+    else:
+        assert len(hoisted) >= 1
+    np.testing.assert_array_equal(np.asarray(P[pos]), np.asarray(whole))
 
 
 def _unskipped_stale_segment(n, lift_levels, segment_rounds):
@@ -553,7 +560,7 @@ def _unskipped_stale_segment(n, lift_levels, segment_rounds):
 
 def test_hoisted_skip_is_bit_identical_segment_by_segment():
     """Skipping the all-sentinel lifting levels (elim.py
-    build_lift_tables / _pos_round_body_stale) changes no segment's
+    _lift_tables / _pos_round_body_stale) changes no segment's
     output: from the same entry state, the hoisted program returns the
     same (loP, hiP, P) and (changed, rounds, live) as the un-skipped
     stale body, on every segment of a stream of RMAT chunks, and its
@@ -591,9 +598,9 @@ def test_hoisted_skip_is_bit_identical_segment_by_segment():
         "the stream must hold segments with some levels skipped, some not"
 
 
-@pytest.mark.parametrize("stale_reuse", [1, 3])
-def test_lift_level_counters_cover_every_full_segment(stale_reuse):
-    """The adaptive driver sums the stale programs' live-level entry:
+@pytest.mark.parametrize("segment_rounds", [2, 3, 4])
+def test_lift_level_counters_cover_every_full_segment(segment_rounds):
+    """The adaptive driver sums the hoisted program's live-level entry:
     live + skipped = full segments x (L-1), and a fold from an empty
     table skips levels (its first stack is all sentinel)."""
     e, n = _cases()["rmat"]
@@ -602,8 +609,9 @@ def test_lift_level_counters_cover_every_full_segment(stale_reuse):
         jnp.asarray(pad_chunk(e, len(e), n)), pos, n)
     stats: dict = {}
     elim_ops.fold_edges_adaptive_pos(
-        jnp.full(n + 1, n, dtype=jnp.int32), loP, hiP, n, segment_rounds=2,
-        small_size=8, host_tail=False, stale_reuse=stale_reuse, stats=stats)
+        jnp.full(n + 1, n, dtype=jnp.int32), loP, hiP, n,
+        segment_rounds=segment_rounds, small_size=8, host_tail=False,
+        stats=stats)
     full = stats.get("full_segments", 0)
     assert full > 0
     assert stats["lift_levels_skipped"] > 0
@@ -620,7 +628,8 @@ def _uncollapsed_warm_segment(P, loP, hiP, threshold, n, **kw):
 
 
 @pytest.mark.parametrize("case", ["distinct_under", "distinct_over",
-                                  "live_under", "carry", "compact"])
+                                  "live_under", "distinct_equal",
+                                  "compact"])
 def test_warm_collapse_decides_on_the_distinct_count(case, monkeypatch):
     """The warm segment collapses duplicate constraints when more slots
     are live than the host-tail threshold (elim.py
@@ -629,8 +638,8 @@ def test_warm_collapse_decides_on_the_distinct_count(case, monkeypatch):
     leaves many duplicates after the warm round, a later chunk few.
     distinct_under: distinct <= threshold < live, so the host tail is
     taken straight after the warm segment, where the uncollapsed
-    driver ran full segments; carry: the same in carry-out mode, so
-    the distinct constraints are carried straight after it; compact:
+    driver ran full segments; distinct_equal: threshold == distinct <
+    live, the same at the boundary of the driver's <=; compact:
     a first chunk of 2^15 with threshold < distinct <= C/4 < C/2 <
     live, so the size//2 compaction runs straight after the warm
     segment, sized by the distinct count; distinct_over: a later chunk
@@ -656,11 +665,13 @@ def test_warm_collapse_decides_on_the_distinct_count(case, monkeypatch):
     live, distinct = (int(x) for x in
                       elim_ops.count_live_distinct(wlo, whi, n))
     threshold = {"distinct_under": (live + distinct) // 2,
-                 "carry": (live + distinct) // 2,
+                 "distinct_equal": distinct,
                  "compact": distinct // 2,
                  "distinct_over": distinct - 1, "live_under": live}[case]
-    if case in ("distinct_under", "carry"):
+    if case == "distinct_under":
         assert distinct <= threshold < live
+    elif case == "distinct_equal":
+        assert distinct == threshold < live
     elif case == "compact":
         assert threshold < distinct <= c // 4 and c // 2 < live
     elif case == "distinct_over":
@@ -681,15 +692,7 @@ def test_warm_collapse_decides_on_the_distinct_count(case, monkeypatch):
 
     with monkeypatch.context() as m:
         m.setattr(elim_ops, "compact_actives", spy)
-        if case == "carry":
-            P_got, _, carry = elim_ops.fold_edges_adaptive_pos_carry(
-                P, loP, hiP, n, host_tail_threshold=threshold, stats=got,
-                **opts)
-            assert int(jnp.sum(carry[0] != n)) == distinct
-            P_got, _ = elim_ops.fold_edges_adaptive_pos(
-                P_got, carry[0], carry[1], n, pos_host=pos_host)
-        else:
-            P_got = fold(got)
+        P_got = fold(got)
     want: dict = {}
     with monkeypatch.context() as m:
         m.setattr(elim_ops, "fold_segment_pos_collapse",
@@ -697,10 +700,10 @@ def test_warm_collapse_decides_on_the_distinct_count(case, monkeypatch):
         P_want = fold(want)
     np.testing.assert_array_equal(np.asarray(P_got), np.asarray(P_want))
     assert want["host_tails"] == 1
-    assert got.get("host_tails", 0) == (0 if case == "carry" else 1)
+    assert got["host_tails"] == 1
     assert got["warm_duplicates"] == \
         (0 if case == "live_under" else live - distinct)
-    if case in ("distinct_under", "carry"):
+    if case in ("distinct_under", "distinct_equal"):
         assert got.get("full_segments", 0) == 0
         assert want["full_segments"] > 0
     elif case == "compact":
@@ -708,9 +711,10 @@ def test_warm_collapse_decides_on_the_distinct_count(case, monkeypatch):
         assert compactions[0] == (c, elim_ops.pow2_at_least(2 * distinct))
     else:
         assert got.get("full_segments", 0) == want.get("full_segments", 0)
-    if case == "carry":
-        assert got["carried_tails"] == 1
-        assert compactions[0] == (c, c)
+    if case == "distinct_equal":
+        # the tail's one compaction, sized by the distinct count
+        assert compactions == [(c, min(elim_ops.pow2_at_least(
+            distinct, floor=1 << 14), c))]
     if case == "distinct_over":
         assert got["full_segments"] > 0
 

@@ -59,23 +59,6 @@ def main():
     ap.add_argument("--tail-divisors", default="8",
                     help="comma list d: host_tail_threshold = C/d "
                          "(0 = keep the auto default)")
-    ap.add_argument("--stale", default="1",
-                    help="comma list of 0/1: per-segment stale lifting "
-                         "tables on full exact-descent segments "
-                         "(BASELINE.md 'stale lifting tables' A/B)")
-    ap.add_argument("--stale-reuse", default="1",
-                    help="comma list of K >= 1: full segments per "
-                         "lifting-stack rebuild (elim.py "
-                         "fold_segment_pos_stale; only with --stale 1)")
-    ap.add_argument("--carry", default="0",
-                    help="comma list of 0/1: carry-over tails between "
-                         "chunks instead of per-chunk host tails "
-                         "(BASELINE.md 'carry-over tails' A/B)")
-    ap.add_argument("--overlap", default="0",
-                    help="comma list of 0/1: resolve host tails in a "
-                         "worker thread overlapped with the next chunk's "
-                         "device rounds, delta re-injection "
-                         "(tail_overlap A/B; excludes --carry 1)")
     ap.add_argument("--reps", type=int, default=1)
     args = ap.parse_args()
 
@@ -111,55 +94,23 @@ def main():
     pos, order = order_ops.elimination_order(deg[:n], n)
     pos_host = np.asarray(pos[:n])  # sheeplint: sync-ok
 
-    def run(chunk_log, warm_name, seg_rounds, lift, tail_div, stale, carry,
-            overlap, reuse=1):
+    def run(chunk_log, warm_name, seg_rounds, lift, tail_div):
         cs = 1 << chunk_log
         # pre-pad + pre-upload all chunks so only fold time is measured
         dev_chunks = [jnp.asarray(pad_chunk(edges[i:i + cs], cs, n))
                       for i in range(0, len(edges), cs)]
         np.asarray(dev_chunks[-1][:2])  # settle uploads
-        from contextlib import nullcontext
-
         stats: dict = {}
         P = jnp.full(n + 1, n, dtype=jnp.int32)
         total = 0
-        carried = None
-        ov_ctx = elim_ops.TailOverlap(n, pos_host) if overlap \
-            else nullcontext()
         t0 = time.perf_counter()
-        with ov_ctx as ov:
-            for d in dev_chunks:
-                if overlap:
-                    ov.drain(False)
-                    carried = ov.take_inject()
-                step = elim_ops.build_chunk_step_adaptive_pos(
-                    P, d, pos, pos_host, n,
-                    lift_levels=lift,
-                    segment_rounds=seg_rounds,
-                    warm_schedule=WARM_SCHEDULES[warm_name], stats=stats,
-                    host_tail_threshold=(cs // tail_div if tail_div else 0),
-                    stale_tables=bool(stale), stale_reuse=reuse,
-                    carry=carried, carry_out=bool(carry) or bool(overlap))
-                if carry:
-                    P, rounds, carried = step
-                elif overlap:
-                    P, rounds, tail = step
-                    carried = None
-                    if int(tail[0].shape[0]):
-                        ov.submit(P, tail[0], tail[1])
-                else:
-                    P, rounds = step
-                total += int(rounds)
-            if overlap:
-                ov.drain(True)
-                carried = ov.take_inject()
-        if carried is not None and int(carried[0].shape[0]):
-            P, rounds = elim_ops.fold_edges_adaptive_pos(
-                P, carried[0], carried[1], n, lift_levels=lift,
+        for d in dev_chunks:
+            P, rounds = elim_ops.build_chunk_step_adaptive_pos(
+                P, d, pos, pos_host, n,
+                lift_levels=lift,
                 segment_rounds=seg_rounds,
-                host_tail_threshold=(cs // tail_div if tail_div else 0),
-                pos_host=pos_host, stats=stats, stale_tables=bool(stale),
-                stale_reuse=reuse)
+                warm_schedule=WARM_SCHEDULES[warm_name], stats=stats,
+                host_tail_threshold=(cs // tail_div if tail_div else 0))
             total += int(rounds)
         np.asarray(P[:8])  # force completion (a host pull)
         dt = time.perf_counter() - t0
@@ -171,24 +122,14 @@ def main():
     seg_rounds_list = [int(x) for x in args.segment_rounds.split(",")]
     lifts = [int(x) for x in args.lift_levels.split(",")]
     tail_divs = [int(x) for x in args.tail_divisors.split(",")]
-    stales = [int(x) for x in args.stale.split(",")]
-    reuses = [int(x) for x in args.stale_reuse.split(",")]
-    carries = [int(x) for x in args.carry.split(",")]
-    overlaps = [int(x) for x in args.overlap.split(",")]
 
     reference = None
     best = None
-    for cl, wn, sr, lv, td, st, ru, ca, ov in itertools.product(
-            chunk_logs, warm_names, seg_rounds_list, lifts, tail_divs,
-            stales, reuses, carries, overlaps):
-        if ca and ov:
-            continue  # mutually exclusive tail strategies
-        if not st and ru > 1:
-            continue  # reuse cadence only exists on the stale path
+    for cl, wn, sr, lv, td in itertools.product(
+            chunk_logs, warm_names, seg_rounds_list, lifts, tail_divs):
         dts = []
         for rep in range(args.reps):
-            P, dt, total, stats = run(cl, wn, sr, lv, td, st, ca, ov,
-                                      reuse=ru)
+            P, dt, total, stats = run(cl, wn, sr, lv, td)
             dts.append(dt)
         dt = min(dts)
         P_np = np.asarray(P)
@@ -196,21 +137,17 @@ def main():
             reference = P_np
         else:
             assert np.array_equal(reference, P_np), \
-                (f"config warm={wn} seg={sr} L={lv} td={td} stale={st} "
-                 f"reuse={ru} carry={ca} overlap={ov} changed the forest!")
+                (f"config warm={wn} seg={sr} L={lv} td={td} "
+                 f"changed the forest!")
         line = {"chunk_log": cl, "warm": wn, "segment_rounds": sr,
-                "lift_levels": lv, "tail_div": td, "stale": st,
-                "stale_reuse": ru, "carry": ca, "overlap": ov,
+                "lift_levels": lv, "tail_div": td,
                 "build_s": round(dt, 2), "rounds": total,
                 "platform": plat, **{k: int(v) for k, v in stats.items()}}
         print(json.dumps(line), flush=True)
-        log(f"chunk=2^{cl} warm={wn:5s} seg={sr} L={lv} td={td} st={st} "
-            f"ru={ru} ca={ca} ov={ov}: {dt:7.2f}s rounds={total} {stats}")
+        log(f"chunk=2^{cl} warm={wn:5s} seg={sr} L={lv} td={td}: "
+            f"{dt:7.2f}s rounds={total} {stats}")
         if best is None or dt < best[0]:
             best = (dt, line)
-    if best is None:
-        log("no runnable configs (every combination was skipped)")
-        sys.exit(2)
     log(f"best: {best[1]}")
 
 
